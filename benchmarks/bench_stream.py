@@ -810,6 +810,8 @@ def main():
     ap.add_argument("--label", default=None,
                     help="trajectory label for this run (default: mode)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         # capacity starts undersized on purpose so the smoke run also
         # covers table growth; chunk = 4 x the large bucket so the scan

@@ -17,13 +17,27 @@ import numpy as np
 
 from repro.kernels.frontier_expand import kernel, ref
 
-SENTINEL = jnp.uint32(kernel.SENTINEL)
+SENTINEL = jnp.uint32(ref.SENTINEL)
+# order-preserving uint32 <-> int32 embedding: flipping the top bit maps
+# [0, 2^32) monotonically onto [INT32_MIN, INT32_MAX], so ref.SENTINEL
+# lands on kernel.SENTINEL and a signed min is the unsigned min
+_FLIP = np.uint32(0x80000000)
+
+
+def _to_i32(x):
+    return jax.lax.bitcast_convert_type(x.astype(jnp.uint32) ^ _FLIP,
+                                        jnp.int32)
+
+
+def _from_i32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.uint32) ^ _FLIP
+
 
 # 'auto' stops densifying above this vertex count even on TPU: the panel
 # kernel visits O(E * NV / (bv * be)) tiles per round while the XLA
 # scatter stays O(E); past ~2^18 vertices the one-hot trade loses.  The
-# compact repair tier (region_vertex_capacity, typically <= 2^12) and
-# query frontiers sit far below it.
+# compact repair tier sweeps region_vertex_capacity = n_vertices / 8
+# slots (2^17 at 2^20 vertices), so it resolves to the kernel here.
 AUTO_MAX_NV = 1 << 18
 
 
@@ -60,9 +74,10 @@ def frontier_min(dst, msg, nv: int, *, impl: str = "auto",
     # padded messages are the min identity anyway
     dst_p = jnp.pad(dst.reshape(1, -1).astype(jnp.int32),
                     ((0, 0), (0, ep - e)), constant_values=-1)
-    msg_p = jnp.pad(m2.astype(jnp.uint32), ((0, fp - f), (0, ep - e)),
-                    constant_values=np.uint32(kernel.SENTINEL))
-    out = kernel.segment_min_u32(
+    msg_p = jnp.pad(_to_i32(m2), ((0, fp - f), (0, ep - e)),
+                    constant_values=kernel.SENTINEL)
+    out = kernel.segment_min_i32(
         dst_p, msg_p, nvp=nvp, bf=bf_eff, bv=bv, be=be,
         interpret=(impl == "pallas_interpret"))[:f, :nv]
+    out = _from_i32(out)
     return out[0] if squeeze else out

@@ -19,8 +19,11 @@ offset.  TOMB chains and wrap-around fall out of the modular offset.
 
 Grid ``(B/bb, C/bc)`` with the table axis innermost, so each lane tile's
 three minima stay resident across the sweep (init to the SENTINEL
-``max_probes`` at panel 0).  All arrays are (1, N) lane-major rows; the
-compare broadcast is (1, bb, bc) -- bb=8, bc=512 stays ~40 KiB of VMEM.
+``max_probes`` at panel 0).  All arrays are (1, N) lane-major rows, so
+the batch tiles are lane-dense: ``bb`` is a multiple of 128, or the whole
+batch when it is smaller (the TPU block rule for a minor dimension).  The
+compare broadcast is (1, bb, bc) -- bb=128, bc=512 is 256 KiB per live
+int32 intermediate, about 2 MiB of VMEM in all.
 """
 from __future__ import annotations
 
@@ -74,7 +77,7 @@ def probe_sweep(u, v, base, src, dst, state, *, max_probes: int, bb: int,
                 bc: int, interpret: bool = True):
     """u/v/base: int32[1, Bp]; src/dst/state: int32[1, C] table rows.
 
-    Bp % bb == 0 and C % bc == 0 (ops.py pads/choses).  Returns three
+    Bp % bb == 0 and C % bc == 0 (ops.py pads/chooses).  Returns three
     int32[1, Bp] offset minima (SENTINEL = max_probes).
     """
     bp = u.shape[1]

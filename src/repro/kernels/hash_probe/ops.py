@@ -28,7 +28,7 @@ def resolve_impl(impl: str, cap: int | None = None) -> str:
 
 
 def probe(src, dst, state, base, u, v, *, max_probes: int,
-          impl: str = "auto", bb: int = 8, bc: int = 512):
+          impl: str = "auto", bb: int = 128, bc: int = 512):
     """Batched open-addressing membership probe.
 
     src/dst: int32[C], state: int{8,32}[C] (0=EMPTY/1=LIVE/2=TOMB), base:
@@ -44,6 +44,9 @@ def probe(src, dst, state, base, u, v, *, max_probes: int,
                          max_probes=max_probes)
     b = u.shape[0]
     bc = min(bc, cap)
+    # lane-dense batch tiles: one whole-batch block up to bb, else bb-wide
+    # blocks (bb % 128 == 0) over the batch padded to a multiple of bb
+    assert bb % 128 == 0, bb
     bp = b if b <= bb else -(-b // bb) * bb
     bb_eff = min(bb, max(bp, 1))
 

@@ -277,11 +277,19 @@ def supervised_stream(directory: str, *, replicas: int = 2,
     child processes, restart-on-death; returns a summary dict, raises
     AssertionError when a slot fails to converge (restarts exhausted or
     safety timeout)."""
+    import jax
+
     from repro.api import GraphClient
     from repro.ckpt.durable import DurableService
     from repro.core import graph_state as gs
     from repro.launch.stream import typed_op_stream
 
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"--supervised is the CPU fault drill (JAX_PLATFORMS=cpu); it "
+            f"refuses the {backend!r} backend, where its replica children "
+            f"would contend with the parent for the chip")
     cfg = _writer_config(nv, edge_capacity=2048)
     writer = DurableService(
         cfg, directory, state=gs.all_singletons(cfg), buckets=(chunk,),

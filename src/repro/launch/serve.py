@@ -210,6 +210,8 @@ def main():
                     help="smscc only: durable store root for --replicas "
                          "/ per-tenant stores for --tenants")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mod = configs.get(args.arch)
     if mod.FAMILY == "lm":
         serve_lm(mod, args.steps)

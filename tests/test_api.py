@@ -22,10 +22,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.api import (AddEdge, AddVertex, AtLeast, CommunityOf,
                        CommunitySizes, Consistency, GraphClient, Reachable,

@@ -32,10 +32,7 @@ import tempfile
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # tier-1 env has no hypothesis: seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.ckpt import checkpoint, oplog  # noqa: F401
 from repro.core import dynamic

@@ -8,10 +8,7 @@ decremental split (Fig 3) scenarios, and the dense repair path.
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # tier-1 env has no hypothesis: seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import baselines, community, dynamic, graph_state as gs
 from oracle import SeqSCC

@@ -1,7 +1,7 @@
 """Launch-layer tests: partition specs, mesh construction (subprocess with
 512 fake devices -- main test process keeps 1 device per the mandate),
 and step building + abstract lowering on the production mesh."""
-import json
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -13,6 +13,8 @@ import pytest
 from repro import configs
 from repro.launch import partition
 from jax.sharding import PartitionSpec as P
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def test_lm_param_specs_match_tree():
@@ -76,10 +78,10 @@ def test_production_mesh_and_lowering_subprocess():
                        capture_output=True, text=True, timeout=540,
                        env={"PYTHONPATH": "src",
                             "PATH": "/usr/bin:/bin",
-                            # skip accelerator-plugin probing: backend
-                            # discovery hangs ~7 min in a stripped env
+                            # without it libtpu probes for a TPU on a
+                            # host that has none, which takes minutes
                             "JAX_PLATFORMS": "cpu"},
-                       cwd="/root/repo")
+                       cwd=ROOT)
     assert "MESH_OK" in r.stdout, r.stderr[-2000:]
 
 
@@ -142,5 +144,5 @@ def test_elastic_restore_different_mesh(tmp_path):
         capture_output=True, text=True, timeout=300,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
              "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        cwd=ROOT)
     assert "ELASTIC_OK" in r.stdout, r.stderr[-1500:]

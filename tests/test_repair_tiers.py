@@ -16,10 +16,7 @@ Covers the tentpole contracts of the tiered dispatcher in
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # tier-1 env has no hypothesis: seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import dynamic, graph_state as gs, scc
 from repro.kernels import reach_blockmm as rb

@@ -326,3 +326,23 @@ def test_graph_client_over_replicaset_read_your_writes(tmp_path):
     client.close()  # shared broker: the set is stopped explicitly
     rs.stop()
     writer.close()
+
+
+def test_supervised_mode_refuses_an_accelerator_backend(tmp_path,
+                                                        monkeypatch):
+    """The multi-process drill spawns replica children that import JAX
+    again; on a chip they would contend with the parent for it, so it
+    refuses any backend but the CPU before writing or spawning."""
+    import subprocess
+
+    import jax
+    from repro.launch import replica
+
+    def no_spawn(*a, **k):
+        raise AssertionError("a replica child was spawned")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    with pytest.raises(RuntimeError, match="CPU fault drill"):
+        replica.supervised_stream(str(tmp_path), replicas=1, steps=1)
+    assert not any(tmp_path.iterdir())

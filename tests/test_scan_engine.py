@@ -21,10 +21,7 @@ Pins the PR-5 tentpole contracts:
 """
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # tier-1 env has no hypothesis: seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import dynamic, graph_state as gs
 from repro.core.service import SCCService

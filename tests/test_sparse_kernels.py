@@ -15,10 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -186,6 +183,12 @@ def test_scc_static_bit_identical(case, shortcut):
 
 # ----------------------------------------------------------- hash_probe ---
 
+# table builders for the strategy below, jitted so that a draw compiles
+# once per shape: op-by-op, every insert re-traces its probe loops
+_build_insert = jax.jit(et.insert, static_argnums=(3,))
+_build_remove = jax.jit(et.remove, static_argnums=(3,))
+
+
 @st.composite
 def table_case(draw):
     """A table built through real et ops (inserts + removes => organic
@@ -198,11 +201,11 @@ def table_case(draw):
     u = rng.integers(0, 50, n_ins).astype(np.int32)
     v = rng.integers(0, 50, n_ins).astype(np.int32)
     table = et.empty(cap)
-    table, _, _ = et.insert(table, jnp.asarray(u), jnp.asarray(v), cap)
+    table, _, _ = _build_insert(table, jnp.asarray(u), jnp.asarray(v), cap)
     # tombstone ~a third of what went in
     n_rem = max(1, n_ins // 3)
-    table, _ = et.remove(table, jnp.asarray(u[:n_rem]),
-                         jnp.asarray(v[:n_rem]), cap)
+    table, _ = _build_remove(table, jnp.asarray(u[:n_rem]),
+                             jnp.asarray(v[:n_rem]), cap)
     b = draw(st.sampled_from([1, 7, 33]))
     qu = rng.integers(0, 60, b).astype(np.int32)  # mix of hits/misses
     qv = rng.integers(0, 60, b).astype(np.int32)
