@@ -582,9 +582,8 @@ def run_tenancy(n_tenants=6, steps=20, nv=256, chunk=16,
     final per-tenant labellings are **bit-identical** between the two
     paths (tenancy is an execution strategy, not a semantics change).
 
-    Reports per-tenant p50/p95 submit->resolve latency (the serving-
-    fairness axis), queue depth / flush causes / pool hit rate, and
-    stacked-lane occupancy."""
+    Reports queue depth / flush causes / pool hit rate and stacked-lane
+    occupancy."""
     import threading
 
     from repro.launch import workload
@@ -675,8 +674,7 @@ def run_tenancy(n_tenants=6, steps=20, nv=256, chunk=16,
     for tid in tids:
         ts = mts.tenant_stats(tid)
         per_tenant.append({"tid": tid, "gen": ts["gen"],
-                           "fallback_chunks": ts["fallback_chunks"],
-                           "p50_s": ts["p50_s"], "p95_s": ts["p95_s"]})
+                           "fallback_chunks": ts["fallback_chunks"]})
     report = {"tenants": n_tenants, "steps": steps, "chunk": chunk,
               "ops": timed_ops,
               "seq_ops_per_s": seq_rate, "multi_ops_per_s": multi_rate,

@@ -46,6 +46,7 @@ from typing import Any, Iterable, Iterator, List, NamedTuple, Sequence, \
 
 import numpy as np
 
+from repro import telemetry
 from repro.api.ops import (CommunityOf, CommunitySizes, Op, QueryOp,
                            SccMembers, UpdateOp, encode_updates)
 from repro.fault import errors as fault_errors
@@ -275,13 +276,19 @@ class GraphClient:
         stamps returned to this client are monotone non-decreasing across
         the whole sequence — and under READ_YOUR_WRITES every query stamp
         is ``>=`` the session token at its submission.
+
+        Each run is one request of :mod:`repro.telemetry`: a
+        ``client.update`` or ``client.read`` span whose records, and those
+        of every layer it calls on this thread, carry one request id.
         """
         results: List[Result] = []
         eff_deadline = self._deadline_s if deadline_s is None \
             else deadline_s
         for cat, run in _runs(ops):
             if cat == "update":
-                results.extend(self._apply_updates(run, eff_deadline))
+                with telemetry.request(), telemetry.span(
+                        "client.update", ops=len(run)):
+                    results.extend(self._apply_updates(run, eff_deadline))
                 continue
             min_gen = self._min_gen(consistency)
             self.queries_submitted += len(run)
@@ -290,7 +297,9 @@ class GraphClient:
                 bfut = self._submit_query_run(cat, run, min_gen)
                 return self._broker.resolve(bfut, min_gen=min_gen,
                                             timeout=remaining)
-            snap = self._with_retry(attempt, eff_deadline)
+            with telemetry.request(), telemetry.span(
+                    "client.read", kind=cat, ops=len(run)):
+                snap = self._with_retry(attempt, eff_deadline)
             # run-level value decode (one C-level conversion per run, not
             # one isinstance chain + numpy index per op)
             gen = int(snap.gen)

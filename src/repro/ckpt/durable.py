@@ -50,6 +50,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro import telemetry
 from repro.ckpt import checkpoint, oplog
 from repro.core import graph_state as gs
 from repro.core.service import SCCService
@@ -233,17 +234,19 @@ class DurableService(SCCService):
         """Apply the WAL tail on top of the restored snapshot (the
         ``_wal is None`` guard in ``_apply_chunk`` keeps replay from
         re-logging itself)."""
-        for rec in oplog.read_log(self._wal_path, from_gen=self.gen):
-            if to_gen is not None and self.gen >= to_gen:
-                break
-            if rec.gen_before < self.gen:
-                continue  # already inside the snapshot
-            if rec.gen_before != self.gen:
-                raise fault_errors.WalGap(
-                    f"WAL gap: record expects generation "
-                    f"{rec.gen_before}, store is at {self.gen}")
-            self._apply_chunk(rec.kind, rec.u, rec.v)
-            self.replayed_wal_records += 1
+        with telemetry.span("wal.replay") as sp:
+            for rec in oplog.read_log(self._wal_path, from_gen=self.gen):
+                if to_gen is not None and self.gen >= to_gen:
+                    break
+                if rec.gen_before < self.gen:
+                    continue  # already inside the snapshot
+                if rec.gen_before != self.gen:
+                    raise fault_errors.WalGap(
+                        f"WAL gap: record expects generation "
+                        f"{rec.gen_before}, store is at {self.gen}")
+                self._apply_chunk(rec.kind, rec.u, rec.v)
+                self.replayed_wal_records += 1
+            sp.attrs["records"] = self.replayed_wal_records
 
     def _attach_wal(self):
         oplog.repair_tail(self._wal_path)
@@ -464,9 +467,10 @@ class DurableService(SCCService):
 
     def _write_snapshot(self, state: gs.GraphState, cfg: gs.GraphConfig,
                         gen: int):
-        checkpoint.save_graph_snapshot(
-            self._snap_path, state, self._snapshot_meta(cfg, gen),
-            keep=self._snapshot_keep)
+        with telemetry.span("snapshot.write", gen=int(gen)):
+            checkpoint.save_graph_snapshot(
+                self._snap_path, state, self._snapshot_meta(cfg, gen),
+                keep=self._snapshot_keep)
         self.snapshot_count += 1
         if self._trim_on_snapshot:
             oplog.trim(self._wal_path, gen)

@@ -77,6 +77,7 @@ try:
 except ImportError:  # non-POSIX: advisory lock degrades to a no-op
     fcntl = None
 
+from repro import telemetry
 from repro.fault import errors as fault_errors
 from repro.fault.inject import fs_fsync, fs_open
 
@@ -503,7 +504,8 @@ class OpLogWriter:
         or fail entirely after it."""
         rec = _encode_record(gen_before, kind, u, v)
         start = self._pos
-        with _wal_lock(self._dir):
+        with telemetry.span("wal.append", bytes=len(rec)), \
+                _wal_lock(self._dir):
             self._assert_unfenced(self._seq + 1)
             try:
                 self._f.write(rec)
@@ -568,8 +570,9 @@ class OpLogWriter:
     def sync(self) -> None:
         if self._unsynced == 0:
             return
-        self._f.flush()
-        fs_fsync(self._f)
+        with telemetry.span("wal.fsync", records=self._unsynced):
+            self._f.flush()
+            fs_fsync(self._f)
         self._unsynced = 0
         self.syncs += 1
 

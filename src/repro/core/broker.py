@@ -49,6 +49,7 @@ from typing import Dict, List, NamedTuple, Sequence, Set
 
 import numpy as np
 
+from repro import telemetry
 from repro.core import service as svc_mod
 from repro.fault import errors as fault_errors
 from repro.fault.inject import maybe_stall
@@ -221,15 +222,20 @@ class QueryBroker:
                 self._pending[k] = []
         if not batch:
             return 0
+        with telemetry.span("broker.flush"):
+            return self._flush_batch(batch, fail_waiting)
+
+    def _flush_batch(self, batch: dict, fail_waiting: bool) -> int:
         # Pin AFTER collecting the batch: a reader already answered at gen
         # g resubmits only after its result arrived, hence after the flush
         # that pinned g -- commits are monotone, so this pin sees >= g.
         # cfg may be read mid-grow relative to st, but the only mutable
         # field (edge_capacity) never enters a query: n_vertices/max_inner
         # are fixed for the service's lifetime.
-        st = self._svc.state
-        cfg = self._svc.cfg
-        gen = int(st.gen)
+        with telemetry.span("broker.pin"):
+            st = self._svc.state
+            cfg = self._svc.cfg
+            gen = int(st.gen)
         # gen-wait hook: split off requests whose floor is above the
         # pinned generation; they wait for a later commit without
         # delaying the ready ones.
@@ -265,7 +271,8 @@ class QueryBroker:
         try:
             served = 0
             for kind, reqs in ready.items():
-                served += self._flush_kind(kind, reqs, st, cfg, gen)
+                with telemetry.span(f"query.{kind}", requests=len(reqs)):
+                    served += self._flush_kind(kind, reqs, st, cfg, gen)
         except BaseException as e:
             for reqs in ready.values():
                 for r in reqs:

@@ -44,9 +44,12 @@ to the region, not the table (the other half of locality of repair):
 
 Tier choice is a runtime ``lax.cond`` inside the one compiled step (no
 extra compilations); every tier produces bit-identical labels.  The
-chosen tier and the region's vertex/edge counts are returned as
+chosen tier, the region's vertex/edge counts and the iterations of the
+FW/BW fixpoint and of the chosen tier are returned as
 :class:`RepairStats` next to the overflow delta, and surfaced by
-``SCCService.stats()``.
+``SCCService.stats()``.  Each phase runs under a ``jax.named_scope``
+(``p1_remove_vertex`` .. ``p4_add_edge``, ``p5_reach``, ``p5_tier``), so
+a profile attributes device operations to phases.
 
 Two step-level fusions keep the *update-heavy* path fast (the paper's
 Fig 4/5 regime, where most ops do not change SCC structure):
@@ -117,6 +120,84 @@ def _first_claim(cand, target, nv, b):
     return cand & (claims[target] == idx)
 
 
+def _repair_tiers(src, dst, live, region, region_v, region_e,
+                  cfg: gs.GraphConfig):
+    """Masked static-SCC labels of ``region`` by the smallest tier it fits.
+
+    Returns ``(labels, tier, scc_rounds)``: labels valid inside the
+    region, the tier code, and the tier's iterations (trim and propagation
+    rounds for the sparse tiers, boolean squarings for the dense one).
+    """
+    # The region is the same for every tier; each tier is a cheaper
+    # execution of the identical masked static-SCC pass.  Tiers nest
+    # smallest-first via lax.cond (one compiled program per cfg -- tier
+    # choice is a runtime branch, never a recompile).
+    nv = cfg.n_vertices
+
+    def repair_full(_):
+        lab, rounds = scc.scc_static_rounds(
+            src, dst, live, region, max_outer=cfg.max_outer,
+            max_inner=cfg.max_inner, spec=cfg.label_spec,
+            shortcut=cfg.shortcut, impl=cfg.sparse_impl)
+        return lab, jnp.int32(TIER_FULL), rounds
+
+    dispatch = repair_full
+
+    # (2) compact sparse: region fits the bounded compact COO.  Edge
+    # slots come from the geometric bucket registry; the smallest
+    # bucket that holds the region's live edges wins (lax.switch over
+    # static shapes).
+    e_buckets = tuple(b for b in cfg.region_edge_buckets
+                      if b < cfg.edge_capacity)
+    if 0 < cfg.region_vertex_capacity < nv and e_buckets:
+        vcap = cfg.region_vertex_capacity
+
+        def compact_branch(ecap):
+            def run(_):
+                lab, _fits, rounds = scc.scc_compact_region(
+                    src, dst, live, region, vcap, ecap,
+                    max_outer=cfg.max_outer, max_inner=cfg.max_inner,
+                    shortcut=cfg.shortcut, impl=cfg.sparse_impl)
+                return lab, jnp.int32(TIER_COMPACT), rounds
+            return run
+
+        branches = [compact_branch(b) for b in e_buckets]
+        bucket_idx = jnp.minimum(
+            jnp.sum((region_e > jnp.asarray(e_buckets, jnp.int32))
+                    .astype(jnp.int32)), len(e_buckets) - 1)
+        fits_compact = (region_v <= vcap) & (region_e <= e_buckets[-1])
+
+        def repair_compact(_):
+            return jax.lax.switch(bucket_idx, branches, None)
+
+        def dispatch(_, fits=fits_compact, below=repair_compact,
+                     above=dispatch):
+            return jax.lax.cond(fits, below, above, None)
+
+    # (1) dense MXU: small enough to densify; the adjacency closure
+    # runs through the injected reach_blockmm boolean mat-mul (Pallas
+    # on TPU, interpret-mode validation on CPU, jnp oracle under
+    # impl='xla').
+    if cfg.dense_capacity > 0:
+        def repair_dense(_):
+            def matmul(a, b):
+                return reach_blockmm.bool_matmul(
+                    a, b, impl=cfg.dense_matmul_impl)
+            lab, _fits = scc.scc_dense_region(src, dst, live, region,
+                                              cfg.dense_capacity,
+                                              matmul=matmul)
+            return (lab, jnp.int32(TIER_DENSE),
+                    jnp.int32(scc.closure_rounds(cfg.dense_capacity)))
+
+        fits_dense = region_v <= cfg.dense_capacity
+
+        def dispatch(_, fits=fits_dense, below=repair_dense,
+                     above=dispatch):
+            return jax.lax.cond(fits, below, above, None)
+
+    return dispatch(None)
+
+
 def _apply_batch_impl(state: gs.GraphState, ops: OpBatch,
                       cfg: gs.GraphConfig):
     """One batch-atomic SMSCC step.
@@ -141,58 +222,66 @@ def _apply_batch_impl(state: gs.GraphState, ops: OpBatch,
                   (ops.v >= 0) & (ops.v < nv), True)
 
     # ---- Phase 1: RemoveVertex --------------------------------------------
-    is_remv = (ops.kind == REM_VERTEX) & in_range
-    cand = is_remv & v_alive[jnp.clip(ops.u, 0, nv - 1)]
-    win_remv = _first_claim(cand, ops.u, nv, b)
-    ok = jnp.where(win_remv, True, ok)
-    killed = jnp.zeros((nv,), jnp.bool_).at[
-        jnp.where(win_remv, ops.u, nv)].set(True, mode="drop")
-    # deletion-affected classes: the old class of every killed vertex
-    affected_rep = jnp.zeros((nv + 1,), jnp.bool_)
-    affected_rep = affected_rep.at[
-        jnp.where(killed, jnp.minimum(ccid, nv), nv)].set(True, mode="drop")
-    v_alive = v_alive & ~killed
-    # the paper's "trim after RemoveVertex": drop all incident edges at once
-    edges, _ = et.remove_incident(edges, killed)
-    ccid = jnp.where(killed, nv, ccid)
+    with jax.named_scope("p1_remove_vertex"):
+        is_remv = (ops.kind == REM_VERTEX) & in_range
+        cand = is_remv & v_alive[jnp.clip(ops.u, 0, nv - 1)]
+        win_remv = _first_claim(cand, ops.u, nv, b)
+        ok = jnp.where(win_remv, True, ok)
+        killed = jnp.zeros((nv,), jnp.bool_).at[
+            jnp.where(win_remv, ops.u, nv)].set(True, mode="drop")
+        # deletion-affected classes: the old class of every killed vertex
+        affected_rep = jnp.zeros((nv + 1,), jnp.bool_)
+        affected_rep = affected_rep.at[
+            jnp.where(killed, jnp.minimum(ccid, nv), nv)].set(
+                True, mode="drop")
+        v_alive = v_alive & ~killed
+        # the paper's "trim after RemoveVertex": drop all incident edges
+        # at once
+        edges, _ = et.remove_incident(edges, killed)
+        ccid = jnp.where(killed, nv, ccid)
 
     # ---- Phase 2: RemoveEdge ----------------------------------------------
-    is_reme = (ops.kind == REM_EDGE) & in_range
-    ends_ok = v_alive[jnp.clip(ops.u, 0, nv - 1)] & \
-        v_alive[jnp.clip(ops.v, 0, nv - 1)]
-    edges, removed = et.remove(edges, ops.u, ops.v, cfg.max_probes,
-                               enable=is_reme & ends_ok,
-                               impl=cfg.sparse_impl)
-    ok = jnp.where(removed, True, ok)
-    same_class = ccid[jnp.clip(ops.u, 0, nv - 1)] == \
-        ccid[jnp.clip(ops.v, 0, nv - 1)]
-    hit = removed & same_class
-    affected_rep = affected_rep.at[
-        jnp.where(hit, jnp.minimum(ccid[jnp.clip(ops.u, 0, nv - 1)], nv),
-                  nv)].set(True, mode="drop")
+    with jax.named_scope("p2_remove_edge"):
+        is_reme = (ops.kind == REM_EDGE) & in_range
+        ends_ok = v_alive[jnp.clip(ops.u, 0, nv - 1)] & \
+            v_alive[jnp.clip(ops.v, 0, nv - 1)]
+        edges, removed = et.remove(edges, ops.u, ops.v, cfg.max_probes,
+                                   enable=is_reme & ends_ok,
+                                   impl=cfg.sparse_impl)
+        ok = jnp.where(removed, True, ok)
+        same_class = ccid[jnp.clip(ops.u, 0, nv - 1)] == \
+            ccid[jnp.clip(ops.v, 0, nv - 1)]
+        hit = removed & same_class
+        affected_rep = affected_rep.at[
+            jnp.where(hit,
+                      jnp.minimum(ccid[jnp.clip(ops.u, 0, nv - 1)], nv),
+                      nv)].set(True, mode="drop")
 
     # ---- Phase 3: AddVertex (paper: new SCC at CCHead, ccCount++) ---------
-    is_addv = (ops.kind == ADD_VERTEX) & in_range
-    cand = is_addv & ~v_alive[jnp.clip(ops.u, 0, nv - 1)]
-    win_addv = _first_claim(cand, ops.u, nv, b)
-    ok = jnp.where(win_addv, True, ok)
-    born = jnp.zeros((nv,), jnp.bool_).at[
-        jnp.where(win_addv, ops.u, nv)].set(True, mode="drop")
-    v_alive = v_alive | born
-    ccid = jnp.where(born, vid, ccid)  # fresh singleton SCC
+    with jax.named_scope("p3_add_vertex"):
+        is_addv = (ops.kind == ADD_VERTEX) & in_range
+        cand = is_addv & ~v_alive[jnp.clip(ops.u, 0, nv - 1)]
+        win_addv = _first_claim(cand, ops.u, nv, b)
+        ok = jnp.where(win_addv, True, ok)
+        born = jnp.zeros((nv,), jnp.bool_).at[
+            jnp.where(win_addv, ops.u, nv)].set(True, mode="drop")
+        v_alive = v_alive | born
+        ccid = jnp.where(born, vid, ccid)  # fresh singleton SCC
 
     # ---- Phase 4: AddEdge --------------------------------------------------
-    is_adde = (ops.kind == ADD_EDGE) & in_range
-    ends_ok = v_alive[jnp.clip(ops.u, 0, nv - 1)] & \
-        v_alive[jnp.clip(ops.v, 0, nv - 1)]
-    enable = is_adde & ends_ok
-    edges, inserted, dropped = et.insert(edges, ops.u, ops.v,
-                                         cfg.max_probes, enable=enable,
-                                         impl=cfg.sparse_impl)
-    ok = jnp.where(inserted, True, ok)
-    # overflow accounting straight from the table's own probe-exhaustion
-    # report -- the host must grow the table and replay these lanes.
-    ovf = jnp.sum(dropped).astype(jnp.int32)
+    with jax.named_scope("p4_add_edge"):
+        is_adde = (ops.kind == ADD_EDGE) & in_range
+        ends_ok = v_alive[jnp.clip(ops.u, 0, nv - 1)] & \
+            v_alive[jnp.clip(ops.v, 0, nv - 1)]
+        enable = is_adde & ends_ok
+        edges, inserted, dropped = et.insert(edges, ops.u, ops.v,
+                                             cfg.max_probes, enable=enable,
+                                             impl=cfg.sparse_impl)
+        ok = jnp.where(inserted, True, ok)
+        # overflow accounting straight from the table's own
+        # probe-exhaustion report -- the host must grow the table and
+        # replay these lanes.
+        ovf = jnp.sum(dropped).astype(jnp.int32)
 
     # ---- Phase 5: unified localized repair ---------------------------------
     src, dst, live = edges.src, edges.dst, edges.state == et.LIVE
@@ -206,97 +295,34 @@ def _apply_batch_impl(state: gs.GraphState, ops: OpBatch,
                            ccid[jnp.clip(ops.v, 0, nv - 1)])
 
     def run_repair(_):
-        seed_f = jnp.zeros((nv,), jnp.bool_).at[
-            jnp.where(straddle, ops.v, nv)].set(True, mode="drop")
-        seed_b = jnp.zeros((nv,), jnp.bool_).at[
-            jnp.where(straddle, ops.u, nv)].set(True, mode="drop")
-        if cfg.fuse_fwbw:
-            fw, bw, _ = reach.fused_fw_bw_reach(
-                src, dst, live, seed_f, seed_b, v_alive, cfg.max_inner,
-                spec=cfg.label_spec, impl=cfg.sparse_impl)
-        else:
-            fw, _ = reach.forward_reach(src, dst, live, seed_f, v_alive,
-                                        cfg.max_inner, spec=cfg.label_spec,
-                                        impl=cfg.sparse_impl)
-            bw, _ = reach.backward_reach(src, dst, live, seed_b, v_alive,
-                                         cfg.max_inner,
-                                         spec=cfg.label_spec,
-                                         impl=cfg.sparse_impl)
-        region = (m_del | (fw & bw)) & v_alive
-        region_v = jnp.sum(region).astype(jnp.int32)
-        region_e = jnp.sum(live & region[src] & region[dst]
-                           ).astype(jnp.int32)
-
-        # Tiered repair dispatch: the region is the same for every tier;
-        # each tier is a cheaper execution of the identical masked
-        # static-SCC pass.  Tiers nest smallest-first via lax.cond (one
-        # compiled program per cfg -- tier choice is a runtime branch,
-        # never a recompile).
-        def repair_full(_):
-            lab = scc.scc_static(src, dst, live, region,
-                                 max_outer=cfg.max_outer,
-                                 max_inner=cfg.max_inner,
-                                 spec=cfg.label_spec,
-                                 shortcut=cfg.shortcut,
-                                 impl=cfg.sparse_impl)
-            return lab, jnp.int32(TIER_FULL)
-
-        dispatch = repair_full
-
-        # (2) compact sparse: region fits the bounded compact COO.  Edge
-        # slots come from the geometric bucket registry; the smallest
-        # bucket that holds the region's live edges wins (lax.switch over
-        # static shapes).
-        e_buckets = tuple(b for b in cfg.region_edge_buckets
-                          if b < cfg.edge_capacity)
-        if 0 < cfg.region_vertex_capacity < nv and e_buckets:
-            vcap = cfg.region_vertex_capacity
-
-            def compact_branch(ecap):
-                def run(_):
-                    lab, _fits = scc.scc_compact_region(
-                        src, dst, live, region, vcap, ecap,
-                        max_outer=cfg.max_outer, max_inner=cfg.max_inner,
-                        shortcut=cfg.shortcut, impl=cfg.sparse_impl)
-                    return lab, jnp.int32(TIER_COMPACT)
-                return run
-
-            branches = [compact_branch(b) for b in e_buckets]
-            bucket_idx = jnp.minimum(
-                jnp.sum((region_e > jnp.asarray(e_buckets, jnp.int32))
-                        .astype(jnp.int32)), len(e_buckets) - 1)
-            fits_compact = (region_v <= vcap) & (region_e <= e_buckets[-1])
-
-            def repair_compact(_):
-                return jax.lax.switch(bucket_idx, branches, None)
-
-            def dispatch(_, fits=fits_compact, below=repair_compact,
-                         above=dispatch):
-                return jax.lax.cond(fits, below, above, None)
-
-        # (1) dense MXU: small enough to densify; the adjacency closure
-        # runs through the injected reach_blockmm boolean mat-mul (Pallas
-        # on TPU, interpret-mode validation on CPU, jnp oracle under
-        # impl='xla').
-        if cfg.dense_capacity > 0:
-            def repair_dense(_):
-                def matmul(a, b):
-                    return reach_blockmm.bool_matmul(
-                        a, b, impl=cfg.dense_matmul_impl)
-                lab, _fits = scc.scc_dense_region(src, dst, live, region,
-                                                  cfg.dense_capacity,
-                                                  matmul=matmul)
-                return lab, jnp.int32(TIER_DENSE)
-
-            fits_dense = region_v <= cfg.dense_capacity
-
-            def dispatch(_, fits=fits_dense, below=repair_dense,
-                         above=dispatch):
-                return jax.lax.cond(fits, below, above, None)
-
-        new_lab, tier = dispatch(None)
+        with jax.named_scope("p5_reach"):
+            seed_f = jnp.zeros((nv,), jnp.bool_).at[
+                jnp.where(straddle, ops.v, nv)].set(True, mode="drop")
+            seed_b = jnp.zeros((nv,), jnp.bool_).at[
+                jnp.where(straddle, ops.u, nv)].set(True, mode="drop")
+            if cfg.fuse_fwbw:
+                fw, bw, reach_rounds = reach.fused_fw_bw_reach(
+                    src, dst, live, seed_f, seed_b, v_alive, cfg.max_inner,
+                    spec=cfg.label_spec, impl=cfg.sparse_impl)
+            else:
+                fw, r_fw = reach.forward_reach(
+                    src, dst, live, seed_f, v_alive, cfg.max_inner,
+                    spec=cfg.label_spec, impl=cfg.sparse_impl)
+                bw, r_bw = reach.backward_reach(
+                    src, dst, live, seed_b, v_alive, cfg.max_inner,
+                    spec=cfg.label_spec, impl=cfg.sparse_impl)
+                reach_rounds = r_fw + r_bw
+            region = (m_del | (fw & bw)) & v_alive
+            region_v = jnp.sum(region).astype(jnp.int32)
+            region_e = jnp.sum(live & region[src] & region[dst]
+                               ).astype(jnp.int32)
+        with jax.named_scope("p5_tier"):
+            new_lab, tier, scc_rounds = _repair_tiers(
+                src, dst, live, region, region_v, region_e, cfg)
         repair = RepairStats(tier=tier, region_vertices=region_v,
-                             region_edges=region_e)
+                             region_edges=region_e,
+                             reach_rounds=reach_rounds,
+                             scc_rounds=scc_rounds)
         return jnp.where(region, new_lab, ccid), repair
 
     if cfg.repair_gate:

@@ -49,14 +49,17 @@ class RepairStats(NamedTuple):
     tier: jax.Array             # int32[]  TIER_DENSE..TIER_SKIP
     region_vertices: jax.Array  # int32[]  |M_del ∪ (FW ∩ BW)| this step
     region_edges: jax.Array     # int32[]  live intra-region edges this step
+    reach_rounds: jax.Array     # int32[]  FW/BW fixpoint iterations
+    scc_rounds: jax.Array       # int32[]  the chosen tier's iterations
 
 
 def repair_skipped() -> RepairStats:
     """The stats a gated (structure-preserving) step reports: no tier ran,
-    no region was materialized."""
-    return RepairStats(tier=jnp.int32(TIER_SKIP),
-                       region_vertices=jnp.int32(0),
-                       region_edges=jnp.int32(0))
+    no region was materialized, no fixpoint iterated."""
+    zero = jnp.int32(0)
+    return RepairStats(tier=jnp.int32(TIER_SKIP), region_vertices=zero,
+                       region_edges=zero, reach_rounds=zero,
+                       scc_rounds=zero)
 
 
 @dataclasses.dataclass(frozen=True)

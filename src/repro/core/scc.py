@@ -54,7 +54,8 @@ def _degrees(src, dst, emask, nv):
 
 
 def trim(src, dst, live, unassigned, vid, ccid, max_iters: int):
-    """Iteratively peel zero-in/out-degree vertices into singleton SCCs."""
+    """Iteratively peel zero-in/out-degree vertices into singleton SCCs.
+    Returns (unassigned, ccid, rounds)."""
     nv = unassigned.shape[0]
 
     def body(carry):
@@ -65,9 +66,9 @@ def trim(src, dst, live, unassigned, vid, ccid, max_iters: int):
         ccid = jnp.where(peel, vid, ccid)
         return (unassigned & ~peel, ccid), jnp.any(peel)
 
-    (unassigned, ccid), _ = reach._fixpoint(body, (unassigned, ccid),
-                                            max_iters)
-    return unassigned, ccid
+    (unassigned, ccid), rounds = reach._fixpoint(body, (unassigned, ccid),
+                                                 max_iters)
+    return unassigned, ccid, rounds
 
 
 @partial(jax.jit, static_argnames=("max_outer", "max_inner", "spec",
@@ -82,20 +83,31 @@ def scc_static(src, dst, live, active, *, max_outer: int, max_inner: int,
     rounds (>= region diameter).  ``spec`` optionally pins the NV-array
     sharding inside the fixpoints (GraphConfig.label_spec).
     """
+    return scc_static_rounds(src, dst, live, active, max_outer=max_outer,
+                             max_inner=max_inner, spec=spec,
+                             shortcut=shortcut, impl=impl)[0]
+
+
+def scc_static_rounds(src, dst, live, active, *, max_outer: int,
+                      max_inner: int, spec=None, shortcut: bool = False,
+                      impl: str = "xla"):
+    """:func:`scc_static` (unjitted) with its iteration count: returns
+    ``(ccid, rounds)``, rounds being every trim and propagation iteration,
+    summed over the outer rounds."""
     nv = active.shape[0]
     vid = jnp.arange(nv, dtype=jnp.int32)
     ccid = jnp.full((nv,), INT32_MAX, jnp.int32)
     unassigned = active
 
     def outer_cond(carry):
-        unassigned, _, it = carry
+        unassigned, _, it, _ = carry
         return jnp.any(unassigned) & (it < max_outer)
 
     def outer_body(carry):
-        unassigned, ccid, it = carry
+        unassigned, ccid, it, rounds = carry
         # (1) trim
-        unassigned, ccid = trim(src, dst, live, unassigned, vid, ccid,
-                                max_inner)
+        unassigned, ccid, r_trim = trim(src, dst, live, unassigned, vid,
+                                        ccid, max_inner)
         # (2) forward-min and backward-min witnesses within unassigned:
         # fwd[v] = min-priority vertex reaching v, bwd[v] = min-priority
         # vertex v reaches.  A vertex sits in a finished SCC exactly when
@@ -105,10 +117,10 @@ def scc_static(src, dst, live, active, *, max_outer: int, max_inner: int,
         # + boolean backward sweep, whose backward phase is pinned at
         # O(diameter) rounds.
         if shortcut:
-            fwd, _ = reach.propagate_min_prio(
+            fwd, r_fwd = reach.propagate_min_prio(
                 src, dst, live, unassigned, max_inner, spec=spec,
                 impl=impl)
-            bwd, _ = reach.propagate_min_prio(
+            bwd, r_bwd = reach.propagate_min_prio(
                 dst, src, live, unassigned, max_inner, spec=spec,
                 impl=impl)
             done = unassigned & (fwd == bwd) & (fwd < nv)
@@ -119,20 +131,21 @@ def scc_static(src, dst, live, active, *, max_outer: int, max_inner: int,
             ccid = jnp.where(done, min_id[jnp.minimum(fwd, nv)], ccid)
         else:
             init = jnp.where(unassigned, vid, INT32_MAX)
-            fwd, _ = reach.propagate_min_labels(
+            fwd, r_fwd = reach.propagate_min_labels(
                 src, dst, live, init, unassigned, max_inner, spec=spec,
                 impl=impl)
-            bwd, _ = reach.propagate_min_labels(
+            bwd, r_bwd = reach.propagate_min_labels(
                 dst, src, live, init, unassigned, max_inner, spec=spec,
                 impl=impl)
             done = unassigned & (fwd == bwd)
             ccid = jnp.where(done, fwd, ccid)
         unassigned = unassigned & ~done
-        return unassigned, ccid, it + 1
+        return unassigned, ccid, it + 1, rounds + r_trim + r_fwd + r_bwd
 
-    _, ccid, _ = jax.lax.while_loop(
-        outer_cond, outer_body, (unassigned, ccid, jnp.int32(0)))
-    return ccid
+    _, ccid, _, rounds = jax.lax.while_loop(
+        outer_cond, outer_body,
+        (unassigned, ccid, jnp.int32(0), jnp.int32(0)))
+    return ccid, rounds
 
 
 # ---------------------------------------------------------------------------
@@ -204,20 +217,21 @@ def scc_compact_region(src, dst, live, region_mask, v_capacity: int,
     Gathers the region once into static ``(v_capacity, e_capacity)``
     sub-arrays and reruns the :func:`scc_static` fixpoints there, so every
     trim/color/backward round costs O(region) gathers and scatters instead
-    of O(table capacity).  Returns ``(ccid int32[NV], fits bool[])`` --
-    labels valid where ``region_mask`` (INT32_MAX sentinel elsewhere) and
-    bit-identical to :func:`scc_static` on the uncompacted
-    ``(src, dst, live, region_mask)`` operands: both
+    of O(table capacity).  Returns ``(ccid int32[NV], fits bool[],
+    rounds int32[])`` -- labels valid where ``region_mask`` (INT32_MAX
+    sentinel elsewhere) and bit-identical to :func:`scc_static` on the
+    uncompacted ``(src, dst, live, region_mask)`` operands: both
     produce canonical min-member-id labels and the compact enumeration is
-    order-preserving.
+    order-preserving; ``rounds`` as :func:`scc_static_rounds` counts them.
     """
     nv = region_mask.shape[0]
     csrc, cdst, celive, ids, valid, _, fits = compact_region(
         src, dst, live, region_mask, v_capacity, e_capacity)
     # no spec: the whole point is that compact operands are small enough to
     # stay replicated, round after round
-    clab = scc_static(csrc, cdst, celive, valid, max_outer=max_outer,
-                      max_inner=max_inner, shortcut=shortcut, impl=impl)
+    clab, rounds = scc_static_rounds(
+        csrc, cdst, celive, valid, max_outer=max_outer,
+        max_inner=max_inner, shortcut=shortcut, impl=impl)
     # a slot scc_static left unassigned (sentinel; only possible when
     # max_outer was exhausted) must stay the sentinel globally too, exactly
     # as the full-sparse tier would report it -- never a clipped real id
@@ -225,7 +239,7 @@ def scc_compact_region(src, dst, live, region_mask, v_capacity: int,
                      ids[jnp.clip(clab, 0, v_capacity - 1)], INT32_MAX)
     ccid = jnp.full((nv,), INT32_MAX, jnp.int32)
     ccid = ccid.at[jnp.where(valid, ids, nv)].set(glab, mode="drop")
-    return ccid, fits
+    return ccid, fits, rounds
 
 
 # ---------------------------------------------------------------------------
@@ -252,6 +266,11 @@ def gather_region(src, dst, live, region_mask, capacity: int):
     return adj[:capacity, :capacity], ids, valid, fits
 
 
+def closure_rounds(r: int) -> int:
+    """Boolean squarings :func:`closure_dense` makes for an R x R block."""
+    return max(1, math.ceil(math.log2(max(r, 2))))
+
+
 def closure_dense(adj, matmul=None):
     """Reflexive-transitive closure via O(log R) boolean squarings.
 
@@ -265,8 +284,7 @@ def closure_dense(adj, matmul=None):
         def matmul(a, b):
             return jnp.einsum("ij,jk->ik", a.astype(jnp.float32),
                               b.astype(jnp.float32)) > 0.0
-    n_steps = max(1, math.ceil(math.log2(max(r, 2))))
-    for _ in range(n_steps):
+    for _ in range(closure_rounds(r)):
         reach_m = reach_m | matmul(reach_m, reach_m)
     return reach_m
 

@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import community, dynamic, edge_table as et
 from repro.core import graph_state as gs
 from repro.fault import errors as fault_errors
@@ -257,6 +258,8 @@ class SCCService:
         self.repair_tier_steps = {name: 0 for name in dynamic.TIER_NAMES}
         self.repair_region_v_max = 0
         self.repair_region_e_max = 0
+        self.repair_reach_rounds = 0
+        self.repair_scc_rounds = 0
 
     # ------------------------------------------------------------ state ---
 
@@ -321,7 +324,8 @@ class SCCService:
     _STAT_ATTRS = ("grow_count", "proactive_grows", "replayed_ops",
                    "compaction_count", "pipelined_chunks",
                    "fallback_chunks", "scanned_chunks", "scan_dispatches",
-                   "repair_region_v_max", "repair_region_e_max")
+                   "repair_region_v_max", "repair_region_e_max",
+                   "repair_reach_rounds", "repair_scc_rounds")
 
     def _stats_snapshot(self) -> dict:
         snap = {a: getattr(self, a) for a in self._STAT_ATTRS}
@@ -358,7 +362,8 @@ class SCCService:
         kind = np.asarray(kind, np.int32)
         u = np.asarray(u, np.int32)
         v = np.asarray(v, np.int32)
-        with self._apply_lock:
+        with self._apply_lock, telemetry.span("service.apply",
+                                              ops=kind.shape[0]):
             entry_state, entry_cfg = self._state, self._cfg
             entry_stats = self._stats_snapshot()
             try:
@@ -379,11 +384,14 @@ class SCCService:
                         ok = np.zeros(kind.shape[0], bool)
                     else:  # prefix super-chunks stay applied
                         self._state = restore
-                    for sl, ops in self._sched.chunks(kind[start:],
-                                                      u[start:], v[start:]):
-                        n_real = sl.stop - sl.start
-                        ok[start + sl.start:start + sl.start + n_real] = \
-                            self._apply_padded(ops)[:n_real]
+                    with telemetry.span("service.replay",
+                                        ops=kind.shape[0] - start):
+                        for sl, ops in self._sched.chunks(
+                                kind[start:], u[start:], v[start:]):
+                            n_real = sl.stop - sl.start
+                            ok[start + sl.start:
+                               start + sl.start + n_real] = \
+                                self._apply_padded(ops)[:n_real]
                 else:
                     self.pipelined_chunks += 1
                 # inserts can only add this chunk's AddEdge lanes; keep
@@ -534,22 +542,21 @@ class SCCService:
             record iff it overflowed (else applies its ok rows/stats)."""
             nonlocal scanned
             rec = pending.popleft()
-            ok_h, ovf_h, r_h = jax.device_get((rec.ok, rec.ovf,
-                                               rec.rstats))
+            with telemetry.span("service.resolve", steps=len(rec.slices)):
+                ok_h, ovf_h, r_h = jax.device_get((rec.ok, rec.ovf,
+                                                   rec.rstats))
             if np.any(ovf_h):
                 return rec
             for sl, row in zip(rec.slices, np.atleast_2d(ok_h)):
                 ok[sl] = row[: sl.stop - sl.start]
-            repair_rows.extend(zip(np.atleast_1d(r_h.tier),
-                                   np.atleast_1d(r_h.region_vertices),
-                                   np.atleast_1d(r_h.region_edges)))
+            repair_rows.extend(zip(*map(np.atleast_1d, r_h)))
             if rec.scanned:
                 scanned += len(rec.slices)
             return None
 
         def commit_telemetry():
-            for t, rv, re_ in repair_rows:
-                self._record_repair(int(t), int(rv), int(re_))
+            for row in repair_rows:
+                self._record_repair(*row)
             self.scanned_chunks += scanned
 
         bad = None
@@ -557,18 +564,20 @@ class SCCService:
                                                     self._scan_lengths):
             k, b = len(slices), int(ops.kind.shape[1])
             entry = None if self._donate else state
-            if k == 1:
-                self._compiled.add(("pipelined", b, self._cfg))
-                state, ok_dev, ovf, rstats = dynamic.apply_batch_inflight(
-                    state, dynamic.OpBatch(ops.kind[0], ops.u[0],
-                                           ops.v[0]),
-                    self._cfg, donate=self._donate)
-            else:
-                self._compiled.add(("scan", k, b, self._cfg))
-                state, ok_dev, ovf, rstats = \
-                    dynamic.apply_batch_scan_inflight(
-                        state, ops, self._cfg, donate=self._donate)
-                self.scan_dispatches += 1
+            with telemetry.span("service.dispatch", k=k, b=b):
+                if k == 1:
+                    self._compiled.add(("pipelined", b, self._cfg))
+                    state, ok_dev, ovf, rstats = \
+                        dynamic.apply_batch_inflight(
+                            state, dynamic.OpBatch(ops.kind[0], ops.u[0],
+                                                   ops.v[0]),
+                            self._cfg, donate=self._donate)
+                else:
+                    self._compiled.add(("scan", k, b, self._cfg))
+                    state, ok_dev, ovf, rstats = \
+                        dynamic.apply_batch_scan_inflight(
+                            state, ops, self._cfg, donate=self._donate)
+                    self.scan_dispatches += 1
             pending.append(self._InFlight(slices, ok_dev, ovf, rstats,
                                           entry, k > 1))
             if len(pending) > self._inflight_window:
@@ -585,10 +594,21 @@ class SCCService:
         commit_telemetry()
         return ok, None
 
-    def _record_repair(self, tier: int, region_v: int, region_e: int):
-        self.repair_tier_steps[dynamic.TIER_NAMES[tier]] += 1
+    def _record_repair(self, tier, region_v, region_e, reach_rounds,
+                       scc_rounds):
+        """Count one resolved step's ``RepairStats`` (host scalars, in
+        leaf order) and emit its ``repair.step`` event."""
+        name = dynamic.TIER_NAMES[int(tier)]
+        region_v, region_e = int(region_v), int(region_e)
+        reach_rounds, scc_rounds = int(reach_rounds), int(scc_rounds)
+        self.repair_tier_steps[name] += 1
         self.repair_region_v_max = max(self.repair_region_v_max, region_v)
         self.repair_region_e_max = max(self.repair_region_e_max, region_e)
+        self.repair_reach_rounds += reach_rounds
+        self.repair_scc_rounds += scc_rounds
+        telemetry.event("repair.step", tier=name, region_v=region_v,
+                        region_e=region_e, reach_rounds=reach_rounds,
+                        scc_rounds=scc_rounds)
 
     def _apply_padded(self, ops: dynamic.OpBatch, depth: int = 0
                       ) -> np.ndarray:
@@ -597,13 +617,15 @@ class SCCService:
                 "grow-and-replay did not converge; "
                 "max_edge_capacity too small for workload?")
         self._compiled.add((int(ops.kind.shape[0]), self._cfg))
-        self._state, ok_dev, ovf_dev, rstats = dynamic.apply_batch_async(
-            self._state, ops, self._cfg)
+        with telemetry.span("service.dispatch", k=1,
+                            b=int(ops.kind.shape[0])):
+            self._state, ok_dev, ovf_dev, rstats = \
+                dynamic.apply_batch_async(self._state, ops, self._cfg)
         # one coalesced host transfer for the step's whole telemetry tuple
-        ok_h, ovf, r_h = jax.device_get((ok_dev, ovf_dev, rstats))
+        with telemetry.span("service.resolve", steps=1):
+            ok_h, ovf, r_h = jax.device_get((ok_dev, ovf_dev, rstats))
         ok = np.array(ok_h)  # own the buffer: replay writes into it below
-        self._record_repair(int(r_h.tier), int(r_h.region_vertices),
-                            int(r_h.region_edges))
+        self._record_repair(*r_h)
         if int(ovf) == 0:
             return ok
         failed = self._failed_add_lanes(ops, ok)
@@ -683,8 +705,9 @@ class SCCService:
             "max_probes too small for workload?")
 
     def _maybe_compact(self):
-        _, tomb = et.fill_stats(self._state.edges)
-        if int(tomb) > self._compact_tomb_frac * self._cfg.edge_capacity:
+        with telemetry.span("service.compact_check"):
+            tomb = int(et.fill_stats(self._state.edges)[1])
+        if tomb > self._compact_tomb_frac * self._cfg.edge_capacity:
             # rehash at the current capacity == compact, but verified: a
             # compaction that would drop an edge escalates to a grow.
             table, cap = self._rehash_preserving(self._cfg.edge_capacity)
@@ -779,5 +802,7 @@ class SCCService:
             "repair_skipped_steps": self.repair_tier_steps["skipped"],
             "repair_region_v_max": self.repair_region_v_max,
             "repair_region_e_max": self.repair_region_e_max,
+            "repair_reach_rounds": self.repair_reach_rounds,
+            "repair_scc_rounds": self.repair_scc_rounds,
             "deduped_resubmits": self.deduped_resubmits,
         }

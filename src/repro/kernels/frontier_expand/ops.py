@@ -59,25 +59,26 @@ def frontier_min(dst, msg, nv: int, *, impl: str = "auto",
     reachability maps reached -> 0, blocked -> SENTINEL); bit-identical
     across impls.
     """
-    impl = resolve_impl(impl, nv)
-    squeeze = msg.ndim == 1
-    m2 = msg[None, :] if squeeze else msg
-    if impl == "xla":
-        out = ref.frontier_min(dst, m2, nv)
+    with jax.named_scope("frontier_min"):
+        impl = resolve_impl(impl, nv)
+        squeeze = msg.ndim == 1
+        m2 = msg[None, :] if squeeze else msg
+        if impl == "xla":
+            out = ref.frontier_min(dst, m2, nv)
+            return out[0] if squeeze else out
+        f, e = m2.shape
+        fp = f if f <= bf else -(-f // bf) * bf
+        bf_eff = min(bf, max(fp, 1))
+        ep = max(be, -(-e // be) * be)
+        nvp = -(-nv // bv) * bv
+        # pad lanes can never land: dst -1 matches no panel vertex id, and the
+        # padded messages are the min identity anyway
+        dst_p = jnp.pad(dst.reshape(1, -1).astype(jnp.int32),
+                        ((0, 0), (0, ep - e)), constant_values=-1)
+        msg_p = jnp.pad(_to_i32(m2), ((0, fp - f), (0, ep - e)),
+                        constant_values=kernel.SENTINEL)
+        out = kernel.segment_min_i32(
+            dst_p, msg_p, nvp=nvp, bf=bf_eff, bv=bv, be=be,
+            interpret=(impl == "pallas_interpret"))[:f, :nv]
+        out = _from_i32(out)
         return out[0] if squeeze else out
-    f, e = m2.shape
-    fp = f if f <= bf else -(-f // bf) * bf
-    bf_eff = min(bf, max(fp, 1))
-    ep = max(be, -(-e // be) * be)
-    nvp = -(-nv // bv) * bv
-    # pad lanes can never land: dst -1 matches no panel vertex id, and the
-    # padded messages are the min identity anyway
-    dst_p = jnp.pad(dst.reshape(1, -1).astype(jnp.int32),
-                    ((0, 0), (0, ep - e)), constant_values=-1)
-    msg_p = jnp.pad(_to_i32(m2), ((0, fp - f), (0, ep - e)),
-                    constant_values=kernel.SENTINEL)
-    out = kernel.segment_min_i32(
-        dst_p, msg_p, nvp=nvp, bf=bf_eff, bv=bv, be=be,
-        interpret=(impl == "pallas_interpret"))[:f, :nv]
-    out = _from_i32(out)
-    return out[0] if squeeze else out

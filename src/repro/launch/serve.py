@@ -188,8 +188,7 @@ def serve_tenants(mod, steps: int, tenants: int, nv: int = 256,
     for tid in tids[:4]:
         print(f"[tenant {tid}] " + " | ".join(
             f"{k}={v}" for k, v in mts.tenant_stats(tid).items()
-            if k in ("gen", "applied_chunks", "fallback_chunks", "grows",
-                     "p50_s", "p95_s")))
+            if k in ("gen", "applied_chunks", "fallback_chunks", "grows")))
     mts.close()
 
 

@@ -49,6 +49,7 @@ grow-and-replay backstop as the only growth path.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -59,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import dynamic, edge_table as et, graph_state as gs
 from repro.core.service import SCCService, _ids_in_range
 from repro.launch.stream import BucketedScheduler
@@ -197,6 +199,20 @@ class TenantEngine:
         self._cfgs_minted: set = set()
         self.flush_count = 0
         self.solo_replays = 0
+        # repair tiers of the real lane-steps committed (NOP padding rows
+        # excluded); "skipped" steps paid the vmapped repair as a select
+        self.lane_tier_steps = {name: 0 for name in dynamic.TIER_NAMES}
+
+    @contextlib.contextmanager
+    def _locked(self):
+        """Hold ``_lock``; the wait for it is an ``engine.lock_wait``
+        span."""
+        with telemetry.span("engine.lock_wait"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
 
     # ------------------------------------------------------------ registry
 
@@ -272,16 +288,16 @@ class TenantEngine:
 
     def tenant_state(self, tid: str) -> gs.GraphState:
         """Committed snapshot of one tenant (lane extraction)."""
-        with self._lock:
+        with self._locked():
             t = self._tenants[tid]
             return _lane(self._groups[t.cfg].states, t.lane)
 
     def tenant_cfg(self, tid: str) -> gs.GraphConfig:
-        with self._lock:
+        with self._locked():
             return self._tenants[tid].cfg
 
     def tenant_gen(self, tid: str) -> int:
-        with self._lock:
+        with self._locked():
             return self._tenants[tid].gen
 
     def wait_for_gen(self, tid: str, gen: int,
@@ -415,9 +431,14 @@ class TenantEngine:
         any other lane.  A tenant may appear at most once per call; the
         admission queue feeds head-of-line chunks in waves to keep the
         oracle's chunk-boundary compaction cadence.
+
+        Each call emits one ``engine.wave`` event: the real lanes, their
+        steps, and how many of those steps each repair tier took.
         """
         out: Dict[str, object] = {}
-        with self._lock:
+        tiers = {name: 0 for name in dynamic.TIER_NAMES}
+        with self._locked(), telemetry.span("engine.apply",
+                                            lanes=len(requests)):
             by_cfg: Dict[gs.GraphConfig, List[_Work]] = {}
             seen = set()
             for tid, kind, u, v in requests:
@@ -434,13 +455,18 @@ class TenantEngine:
                           self._pack_super_chunks(kind, u, v))
                 by_cfg.setdefault(t.cfg, []).append(w)
             for cfg, works in by_cfg.items():
-                self._apply_cfg_group(cfg, works, out)
+                self._apply_cfg_group(cfg, works, out, tiers)
             self.flush_count += 1
+            for name, n in tiers.items():
+                self.lane_tier_steps[name] += n
             self._commit_cv.notify_all()
+        telemetry.event("engine.wave", lanes=len(requests),
+                        lane_steps=sum(tiers.values()),
+                        **{f"tier_{k}": v for k, v in tiers.items()})
         return out
 
     def _apply_cfg_group(self, cfg: gs.GraphConfig, works: List[_Work],
-                         out: dict):
+                         out: dict, tiers: dict):
         # The flush works on ONE [W]-stacked scratch pytree (`cur`) and
         # moves data by whole-batch gather/scatter, never by per-lane
         # slicing: eager per-lane ops (`a[i]`, `a.at[i].set`) cost a
@@ -461,7 +487,7 @@ class TenantEngine:
             lidx = jnp.asarray(np.asarray(lanes, np.int32))
             cur = jax.tree.map(lambda a: a[lidx], group.states)
         n_rows = len(works)
-        xfers: List[tuple] = []       # [(ok [tb,K,B], ovf [tb,K])]
+        xfers: List[tuple] = []       # [(ok [tb,K,B], ovf, tier [tb,K])]
         # --- rounds of vmapped dispatches (async; no host sync) -------
         while True:
             active = [w for w in works if w.pos < len(w.pieces)]
@@ -483,49 +509,53 @@ class TenantEngine:
         # --- compaction probe, amortized into the one sync ------------
         live_tomb = _vmapped_fill_stats(cur.edges)
         # --- the flush's single host transfer --------------------------
-        host_xfers, (live, tomb) = jax.device_get((xfers, live_tomb))
-        # --- per-lane commit / solo replay -----------------------------
-        fast: List[_Work] = []
-        for w in works:
-            host_pieces = [(host_xfers[xi][0][r], host_xfers[xi][1][r])
-                           for _, xi, r in w.refs]
-            total_ovf = sum(int(np.sum(ovf)) for _, ovf in host_pieces)
-            if total_ovf == 0:
-                ok = np.zeros(w.kind.shape[0], bool)
-                steps = 0
-                for (slices, _, _), (ok_kb, _) in zip(w.refs,
-                                                      host_pieces):
-                    for j, sl in enumerate(slices):
-                        ok[sl] = ok_kb[j, :sl.stop - sl.start]
-                    steps += len(slices)
-                w.ok = ok
-                w.t.gen += steps
-                w.t.applied_chunks += 1
-                fast.append(w)
-            else:
-                self._solo_replay(cfg, w)
-        # --- commit fast-path rows back into the stack -----------------
-        if fast:
-            if whole and len(fast) == n_rows:
-                group.states = cur
-            else:
-                frows = jnp.asarray(np.asarray([w.row for w in fast],
-                                               np.int32))
-                flanes = jnp.asarray(np.asarray(
-                    [w.t.lane for w in fast], np.int32))
-                group.states = jax.tree.map(
-                    lambda g, c: g.at[flanes].set(c[frows]),
-                    group.states, cur)
-        # --- oracle-cadence compaction (post-chunk tombstone check) ----
-        for w, work_live, work_tomb in zip(works, live, tomb):
-            if w.error is not None or w.compacted_solo:
-                continue
-            if int(work_tomb) > self._compact_tomb_frac * \
-                    w.t.cfg.edge_capacity:
-                self._compact_tenant(w.t)
-        for w in works:
-            out[w.t.tid] = w.error if w.error is not None \
-                else (w.ok, w.t.gen)
+        with telemetry.span("engine.resolve", lanes=n_rows):
+            host_xfers, (_, tomb) = jax.device_get((xfers, live_tomb))
+        with telemetry.span("engine.commit", lanes=n_rows):
+            # --- per-lane commit / solo replay -------------------------
+            fast: List[_Work] = []
+            for w in works:
+                host_pieces = [tuple(x[r] for x in host_xfers[xi])
+                               for _, xi, r in w.refs]
+                total_ovf = sum(int(np.sum(ovf))
+                                for _, ovf, _ in host_pieces)
+                if total_ovf == 0:
+                    ok = np.zeros(w.kind.shape[0], bool)
+                    steps = 0
+                    for (slices, _, _), (ok_kb, _, tier_k) in zip(
+                            w.refs, host_pieces):
+                        for j, sl in enumerate(slices):
+                            ok[sl] = ok_kb[j, :sl.stop - sl.start]
+                            tiers[dynamic.TIER_NAMES[int(tier_k[j])]] += 1
+                        steps += len(slices)
+                    w.ok = ok
+                    w.t.gen += steps
+                    w.t.applied_chunks += 1
+                    fast.append(w)
+                else:
+                    self._solo_replay(cfg, w, tiers)
+            # --- commit fast-path rows back into the stack -------------
+            if fast:
+                if whole and len(fast) == n_rows:
+                    group.states = cur
+                else:
+                    frows = jnp.asarray(np.asarray([w.row for w in fast],
+                                                   np.int32))
+                    flanes = jnp.asarray(np.asarray(
+                        [w.t.lane for w in fast], np.int32))
+                    group.states = jax.tree.map(
+                        lambda g, c: g.at[flanes].set(c[frows]),
+                        group.states, cur)
+            # --- oracle-cadence compaction (post-chunk tombstone check)
+            for w, work_tomb in zip(works, tomb):
+                if w.error is not None or w.compacted_solo:
+                    continue
+                if int(work_tomb) > self._compact_tomb_frac * \
+                        w.t.cfg.edge_capacity:
+                    self._compact_tenant(w.t)
+            for w in works:
+                out[w.t.tid] = w.error if w.error is not None \
+                    else (w.ok, w.t.gen)
 
     def _dispatch(self, cfg: gs.GraphConfig, k: int, b: int, tb: int,
                   ws: List[_Work], cur, n_rows: int, xfers: list):
@@ -552,7 +582,8 @@ class TenantEngine:
             _, wk, wu, wv = w.pieces[w.pos]
             pk[i], pu[i], pv[i] = wk, wu, wv
         ops = dynamic.make_ops(pk, pu, pv)
-        new_states, ok, ovf, _ = _vmapped_scan(sub, ops, cfg)
+        with telemetry.span("engine.dispatch", tb=tb, k=k, b=b):
+            new_states, ok, ovf, rstats = _vmapped_scan(sub, ops, cfg)
         if full:
             cur = new_states
         else:
@@ -562,7 +593,7 @@ class TenantEngine:
             cur = jax.tree.map(lambda c, n: c.at[sidx].set(n),
                                cur, keep)
         xi = len(xfers)
-        xfers.append((ok, ovf))
+        xfers.append((ok, ovf, rstats.tier))
         for i, w in enumerate(ws):
             w.refs.append((w.pieces[w.pos][0], xi, i))
         return cur
@@ -581,7 +612,7 @@ class TenantEngine:
                           scan_lengths=self._scan_lengths,
                           proactive_grow=False)
 
-    def _solo_replay(self, cfg: gs.GraphConfig, w: _Work):
+    def _solo_replay(self, cfg: gs.GraphConfig, w: _Work, tiers: dict):
         """A doomed lane's chunk re-runs alone through grow-and-replay.
 
         The lane's vmapped outputs are discarded (its stack slot still
@@ -602,6 +633,8 @@ class TenantEngine:
             w.error = e
             return
         t.fallback_chunks += 1
+        for name, n in svc.repair_tier_steps.items():
+            tiers[name] += n
         t.grow_count += svc.grow_count
         t.replayed_ops += svc.replayed_ops
         t.compaction_count += svc.compaction_count
@@ -646,7 +679,7 @@ class TenantEngine:
 
     def _query_many(self, items, *, with_v: bool):
         out = {}
-        with self._lock:
+        with self._locked():
             by_cfg: Dict[gs.GraphConfig, list] = {}
             for tid, u, v in items:
                 t = self._tenants[tid]
@@ -708,6 +741,7 @@ class TenantEngine:
                 "tenants": len(self._tenants),
                 "flushes": self.flush_count,
                 "solo_replays": self.solo_replays,
+                "lane_tier_steps": dict(self.lane_tier_steps),
                 "compile_count": self.compile_count,
                 "compile_bound": self.compile_bound,
                 "query_shapes": len(self._query_compiled),

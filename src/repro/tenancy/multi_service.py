@@ -403,7 +403,6 @@ class MultiTenantService:
             else:
                 tel = {"gen": h.parked_gen,
                        "edge_capacity": h.parked_cfg.edge_capacity}
-            tel.update(self._queue.latency_quantiles(tid))
             tel["resident"] = h.resident
             tel["evictions"] = h.evictions
             tel["rehydrations"] = h.rehydrations
@@ -414,7 +413,7 @@ class MultiTenantService:
 
     def stats(self) -> dict:
         """Aggregate serving telemetry: tenant census, engine registry /
-        occupancy, and admission-queue depth/flush/latency counters."""
+        occupancy, and admission-queue depth/flush/wait counters."""
         with self._lock:
             resident = sum(1 for h in self._tenants.values()
                            if h.resident)

@@ -31,6 +31,7 @@ from typing import Callable, Dict, Optional, Sequence
 
 import numpy as np
 
+from repro import telemetry
 from repro.fault.errors import DeadlineExceeded, Unavailable
 from repro.fault.inject import maybe_stall
 
@@ -105,14 +106,18 @@ class TransferBufferPool:
 
 
 class _Ticket:
-    __slots__ = ("tid", "buf", "n", "t_submit", "event", "ok", "gen",
-                 "error")
+    __slots__ = ("tid", "buf", "n", "t_submit_ns", "span", "req", "event",
+                 "ok", "gen", "error")
 
-    def __init__(self, tid: str, buf: _Buffers, n: int, t_submit: float):
+    def __init__(self, tid: str, buf: _Buffers, n: int, t_submit_ns: int):
         self.tid = tid
         self.buf = buf
         self.n = n
-        self.t_submit = t_submit
+        self.t_submit_ns = t_submit_ns
+        # the submitter's span and request: the ticket's wait ends on the
+        # leader's thread, which records it under them
+        self.span = telemetry.current_span()
+        self.req = telemetry.current_request()
         self.event = threading.Event()
         self.ok = None
         self.gen = None
@@ -134,14 +139,18 @@ class WorkQueue:
     There is no dispatcher thread: the first blocked submitter *is* the
     dispatcher (leader), so an idle queue costs nothing and shutdown is
     trivial.  ``flush()`` drains synchronously (tests / checkpoints).
+
+    Telemetry: each submit is a ``queue.submit`` span; each admitted
+    ticket's wait from enqueue to the start of the wave that carries it is
+    a ``queue.wait`` record (summed in ``stats()["wait_s"]``); each wave
+    is a ``queue.wave`` span on the leader's thread.
     """
 
     def __init__(self, apply_fn: Callable, *,
                  max_pending_ops: int = 8192,
                  coalesce_ops: int = 1024,
                  flush_deadline_s: float = 0.002,
-                 pool: TransferBufferPool | None = None,
-                 latency_window: int = 512):
+                 pool: TransferBufferPool | None = None):
         self._apply_fn = apply_fn
         self._max_pending_ops = max_pending_ops
         self._coalesce_ops = coalesce_ops
@@ -151,13 +160,12 @@ class WorkQueue:
         self._pending: "OrderedDict[str, deque]" = OrderedDict()
         self._pending_ops = 0
         self._leader_active = False
-        self._latency: Dict[str, deque] = {}
-        self._latency_window = latency_window
         self.rejects = 0
         self.flush_causes = {"size": 0, "deadline": 0, "explicit": 0}
         self.waves = 0
         self.depth_max = 0
         self.submitted = 0
+        self.wait_s = 0.0
 
     # -------------------------------------------------------------- submit
 
@@ -169,32 +177,33 @@ class WorkQueue:
         a failed chunk left the tenant untouched)."""
         kind = np.asarray(kind, np.int32)
         n = kind.shape[0]
-        now = time.perf_counter()
-        with self._cv:
-            if self._pending_ops + n > self._max_pending_ops:
-                self.rejects += 1
-                raise QueueFull(retry_after=max(self._flush_deadline_s,
-                                                1e-3))
-            buf = self.pool.acquire(n)
-            buf.kind[:n] = kind
-            buf.u[:n] = np.asarray(u, np.int32)
-            buf.v[:n] = np.asarray(v, np.int32)
-            tk = _Ticket(tid, buf, n, now)
-            self._pending.setdefault(tid, deque()).append(tk)
-            self._pending_ops += n
-            self.submitted += 1
-            self.depth_max = max(self.depth_max, self._pending_ops)
-            lead = not self._leader_active
+        with telemetry.span("queue.submit", tenant=tid, ops=n):
+            with self._cv:
+                if self._pending_ops + n > self._max_pending_ops:
+                    self.rejects += 1
+                    raise QueueFull(retry_after=max(self._flush_deadline_s,
+                                                    1e-3))
+                buf = self.pool.acquire(n)
+                buf.kind[:n] = kind
+                buf.u[:n] = np.asarray(u, np.int32)
+                buf.v[:n] = np.asarray(v, np.int32)
+                tk = _Ticket(tid, buf, n, time.perf_counter_ns())
+                self._pending.setdefault(tid, deque()).append(tk)
+                self._pending_ops += n
+                self.submitted += 1
+                self.depth_max = max(self.depth_max, self._pending_ops)
+                lead = not self._leader_active
+                if lead:
+                    self._leader_active = True
+                elif self._pending_ops >= self._coalesce_ops:
+                    self._cv.notify_all()  # wake the waiting leader early
             if lead:
-                self._leader_active = True
-            elif self._pending_ops >= self._coalesce_ops:
-                self._cv.notify_all()   # wake the waiting leader early
-        if lead:
-            self._lead(tk)
-        if not tk.event.wait(timeout):
-            raise DeadlineExceeded(
-                f"chunk for tenant {tid!r} not flushed within {timeout}s"
-                f" (result may still land; do not blind-retry)")
+                self._lead(tk)
+            if not tk.event.wait(timeout):
+                raise DeadlineExceeded(
+                    f"chunk for tenant {tid!r} not flushed within "
+                    f"{timeout}s (result may still land; do not "
+                    f"blind-retry)")
         if tk.error is not None:
             raise tk.error
         return tk.ok, tk.gen
@@ -217,7 +226,7 @@ class WorkQueue:
         self._drain("explicit")
 
     def _lead(self, tk: _Ticket):
-        deadline = tk.t_submit + self._flush_deadline_s
+        deadline = tk.t_submit_ns * 1e-9 + self._flush_deadline_s
         cause = "deadline"
         with self._cv:
             while self._pending_ops < self._coalesce_ops:
@@ -248,13 +257,19 @@ class WorkQueue:
                     self._leader_active = False
                     self._cv.notify_all()
                     return
-            try:
-                results = self._apply_fn(
-                    [(t.tid, t.buf.kind[:t.n], t.buf.u[:t.n],
-                      t.buf.v[:t.n]) for t in wave])
-            except Exception as e:      # engine-level failure: fail wave
-                results = {t.tid: e for t in wave}
-            t_done = time.perf_counter()
+                t_wave = time.perf_counter_ns()
+                for t in wave:
+                    telemetry.record("queue.wait", t.t_submit_ns, t_wave,
+                                     parent=t.span, req=t.req,
+                                     tenant=t.tid)
+                    self.wait_s += (t_wave - t.t_submit_ns) * 1e-9
+            with telemetry.span("queue.wave", lanes=len(wave), cause=cause):
+                try:
+                    results = self._apply_fn(
+                        [(t.tid, t.buf.kind[:t.n], t.buf.u[:t.n],
+                          t.buf.v[:t.n]) for t in wave])
+                except Exception as e:  # engine-level failure: fail wave
+                    results = {t.tid: e for t in wave}
             for t in wave:
                 res = results.get(t.tid)
                 if isinstance(res, Exception) or res is None:
@@ -262,25 +277,11 @@ class WorkQueue:
                         f"engine returned no result for {t.tid!r}")
                 else:
                     t.ok, t.gen = res
-                lat = self._latency.setdefault(
-                    t.tid, deque(maxlen=self._latency_window))
-                lat.append(t_done - t.t_submit)
                 self.pool.release(t.buf)
                 t.event.set()
             self.waves += 1
 
     # --------------------------------------------------------------- stats
-
-    def latency_quantiles(self, tid: str) -> dict:
-        """p50/p95 submit->resolve latency (seconds) over the sliding
-        window, the serving-fairness axis the bench tracks per tenant."""
-        lat = self._latency.get(tid)
-        if not lat:
-            return {"p50_s": None, "p95_s": None, "samples": 0}
-        arr = np.asarray(lat)
-        return {"p50_s": round(float(np.percentile(arr, 50)), 6),
-                "p95_s": round(float(np.percentile(arr, 95)), 6),
-                "samples": int(arr.shape[0])}
 
     def stats(self) -> dict:
         with self._cv:
@@ -293,6 +294,7 @@ class WorkQueue:
                 "submitted": self.submitted,
                 "rejects": self.rejects,
                 "waves": self.waves,
+                "wait_s": self.wait_s,
                 "flush_causes": dict(self.flush_causes),
                 "pool": self.pool.stats(),
             }

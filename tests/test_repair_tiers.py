@@ -149,8 +149,9 @@ def test_compact_region_roundtrip_labels():
         region = jnp.asarray(rng.random(NV) < 0.6)
         want = scc.scc_static(src, dst, live, region, max_outer=NV,
                               max_inner=NV + 2)
-        got, fits = scc.scc_compact_region(src, dst, live, region, NV, 128,
-                                           max_outer=NV, max_inner=NV + 2)
+        got, fits, _ = scc.scc_compact_region(
+            src, dst, live, region, NV, 128, max_outer=NV,
+            max_inner=NV + 2)
         assert bool(fits)
         np.testing.assert_array_equal(
             np.where(np.asarray(region), np.asarray(got), 0),
@@ -169,8 +170,8 @@ def test_compact_region_preserves_unassigned_sentinel():
     region = jnp.zeros((NV,), bool).at[:4].set(True)
     want = scc.scc_static(src, dst, live, region, max_outer=1,
                           max_inner=NV)
-    got, fits = scc.scc_compact_region(src, dst, live, region, 16, 16,
-                                       max_outer=1, max_inner=NV)
+    got, fits, _ = scc.scc_compact_region(src, dst, live, region, 16, 16,
+                                          max_outer=1, max_inner=NV)
     assert bool(fits)
     np.testing.assert_array_equal(np.asarray(got)[:4], np.asarray(want)[:4])
     sent = np.iinfo(np.int32).max

@@ -155,20 +155,19 @@ def test_scan_matches_sequential_steps(k, op_list):
     st_scan, ok_s, ovf_s, r_s = dynamic.apply_batch_scan(
         state0, dynamic.make_ops(kk, uu, vv), cfg)
     st_seq = state0
-    oks, ovfs, tiers, rvs = [], [], [], []
+    oks, ovfs, repairs = [], [], []
     for r in range(k):
         st_seq, ok1, ovf1, r1 = dynamic.apply_batch_async(
             st_seq, dynamic.make_ops(kk[r], uu[r], vv[r]), cfg)
         oks.append(np.asarray(ok1))
         ovfs.append(int(ovf1))
-        tiers.append(int(r1.tier))
-        rvs.append(int(r1.region_vertices))
+        repairs.append([int(x) for x in r1])
     assert np.asarray(st_scan.ccid).tolist() == \
         np.asarray(st_seq.ccid).tolist()
     assert np.asarray(ok_s).tolist() == np.stack(oks).tolist()
     assert np.asarray(ovf_s).tolist() == ovfs
-    assert np.asarray(r_s.tier).tolist() == tiers
-    assert np.asarray(r_s.region_vertices).tolist() == rvs
+    # every RepairStats leaf, fixpoint round counts included
+    assert np.stack([np.asarray(x) for x in r_s], 1).tolist() == repairs
     assert int(st_scan.gen) == int(st_seq.gen) == k
     assert int(st_scan.overflow) == int(st_seq.overflow)
 
