@@ -13,10 +13,14 @@ raw-state helpers.
 
 Consistency contract (see ``docs/SERVICE_API.md``):
 
-* every flush pins ``service.state`` exactly once -- all answers of that
-  flush share one generation, and the pinned state is always a fully
-  committed snapshot (the service never publishes in-flight pipeline
-  states, and the pipeline donates only its own private double buffer);
+* every flush pins one committed view exactly once, through
+  ``service.pin()`` -> ``(state, cfg, gen)`` with ``gen`` a host int of
+  that same view -- all answers of that flush share one generation, and
+  the pinned state is always a fully committed snapshot (the service
+  never publishes in-flight pipeline states, and the pipeline donates
+  only its own private double buffer).  A tenant's pin reads the
+  engine's published view and takes no engine lock, so it never waits
+  behind a wave;
 * the snapshot is pinned *after* the pending set is collected, so a
   reader that saw generation ``g`` and then submits again can only be
   answered at a generation ``>= g`` (monotone reads per reader);
@@ -229,13 +233,8 @@ class QueryBroker:
         # Pin AFTER collecting the batch: a reader already answered at gen
         # g resubmits only after its result arrived, hence after the flush
         # that pinned g -- commits are monotone, so this pin sees >= g.
-        # cfg may be read mid-grow relative to st, but the only mutable
-        # field (edge_capacity) never enters a query: n_vertices/max_inner
-        # are fixed for the service's lifetime.
         with telemetry.span("broker.pin"):
-            st = self._svc.state
-            cfg = self._svc.cfg
-            gen = int(st.gen)
+            st, cfg, gen = self._svc.pin()
         # gen-wait hook: split off requests whose floor is above the
         # pinned generation; they wait for a later commit without
         # delaying the ready ones.

@@ -63,7 +63,7 @@ import dataclasses
 import threading
 import time
 from functools import partial
-from typing import NamedTuple, Sequence
+from typing import NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -275,6 +275,14 @@ class SCCService:
     @property
     def gen(self) -> int:
         return int(self._committed.gen)
+
+    def pin(self) -> Tuple[gs.GraphState, gs.GraphConfig, int]:
+        """``(state, cfg, gen)`` for one reader flush, from ONE read of
+        the committed pointer, with ``gen`` as a host int.  ``cfg`` may
+        be read mid-grow relative to the state, but its only mutable
+        field (``edge_capacity``) never enters a query."""
+        st = self._committed
+        return st, self._cfg, int(st.gen)
 
     @property
     def compile_count(self) -> int:

@@ -36,7 +36,7 @@ from jax.profiler import TraceAnnotation
 __all__ = ["Record", "RING_SIZE", "span", "record", "event", "request",
            "current_span", "current_request", "records"]
 
-RING_SIZE = 1 << 16
+RING_SIZE = 1 << 18
 
 
 class Record(NamedTuple):

@@ -41,6 +41,9 @@ Design rules (all load-bearing for the differential oracle test):
   ``fill_stats`` over the flushed lanes (amortized into the flush's
   single host sync) and compacts over-threshold lanes through the same
   throwaway-service path.
+* **Reads pin a published view.**  Every mutation ends by publishing an
+  immutable ``tid -> (stack, lane, gen, cfg)`` map; :meth:`pin` reads it
+  without the engine lock, so a read never waits behind a wave.
 
 Engine parity with the oracle assumes ``proactive_grow=False`` (the
 service default): proactive growth is a heuristic that changes *when*
@@ -54,7 +57,7 @@ import dataclasses
 import threading
 import time
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -108,8 +111,31 @@ def _stack(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
+# Lane gathers and scatters run as ONE program over the whole pytree:
+# per-leaf eager ops would dispatch a program per leaf, and each dispatch
+# waits again for the interpreter lock that busy reader threads hold.
+# Indices are traced, so there is one compile per shape, not per lane.
+
+@jax.jit
+def _take(tree, idx):
+    """Rows ``idx`` (a scalar: one lane) of every leaf."""
+    return jax.tree.map(lambda a: a[idx], tree)
+
+
+@jax.jit
+def _put(tree, idx, src, rows):
+    """``tree`` with rows ``idx`` set to ``src``'s rows ``rows``; an
+    index past the end is dropped (a padding row)."""
+    return jax.tree.map(lambda a, b: a.at[idx].set(b[rows], mode="drop"),
+                        tree, src)
+
+
+def _rows(idx) -> np.ndarray:
+    return np.asarray(idx, np.int32)
+
+
 def _lane(tree, i: int):
-    return jax.tree.map(lambda a: a[i], tree)
+    return _take(tree, np.int32(i))
 
 
 def _set_lane(tree, i: int, lane):
@@ -129,6 +155,14 @@ class _Tenant:
     grow_count: int = 0
     replayed_ops: int = 0
     compaction_count: int = 0
+
+
+class _View(NamedTuple):
+    """One tenant's entry in the engine's published read view."""
+    states: object             # its group's committed stack
+    lane: int
+    gen: int
+    cfg: gs.GraphConfig
 
 
 class _Group:
@@ -187,10 +221,14 @@ class TenantEngine:
         self._compact_tomb_frac = compact_tomb_frac
         self._groups: Dict[gs.GraphConfig, _Group] = {}
         self._tenants: Dict[str, _Tenant] = {}
-        # one lock serializes all structural mutation; queries extract
-        # committed lanes under it (group states only move at flush end)
+        # one lock serializes all structural mutation; each mutation
+        # ends by publishing the committed read view (_publish), which
+        # tenant reads take without the lock
         self._lock = threading.RLock()
         self._commit_cv = threading.Condition(self._lock)
+        self._view: Dict[str, _View] = {}
+        self._pins = 0
+        self._pins_lock = threading.Lock()
         # compiled-entry registries (update entries are the bounded ones;
         # query/fill-stats entries are separately cached, like the
         # service's query shapes)
@@ -265,6 +303,7 @@ class TenantEngine:
             self._tenants[tid] = _Tenant(
                 tid=tid, cfg=cfg, lane=lane,
                 gen=int(state.gen) if gen is None else int(gen))
+            self._publish()
 
     def remove_tenant(self, tid: str) -> Tuple[gs.GraphState,
                                                gs.GraphConfig, int]:
@@ -276,6 +315,7 @@ class TenantEngine:
             state = _lane(group.states, t.lane)
             group.lanes[t.lane] = None
             self._compact_group(group)
+            self._publish()
             return state, t.cfg, t.gen
 
     def has_tenant(self, tid: str) -> bool:
@@ -286,19 +326,40 @@ class TenantEngine:
         with self._lock:
             return list(self._tenants)
 
+    def _publish(self):
+        """Rebuild the read view from the bookkeeping and publish it with
+        one assignment.  Called under ``_lock`` at the end of every
+        mutation (``create_tenant``, ``remove_tenant``, ``apply_chunks``
+        with its solo replays, compactions and lane moves), never midway,
+        so a view only names committed lanes and their generations.
+        ``group.states`` is only ever replaced, never written in place,
+        so a stack a view names stays a committed snapshot."""
+        self._view = {tid: _View(self._groups[t.cfg].states, t.lane,
+                                 t.gen, t.cfg)
+                      for tid, t in self._tenants.items()}
+
+    def pin(self, tid: str) -> Tuple[gs.GraphState, gs.GraphConfig, int]:
+        """``(state, cfg, gen)`` of ``tid``'s last commit, all from one
+        published view and without ``_lock``: a read never waits behind
+        a wave.  The lane is sliced at once, so the pin keeps no
+        reference to the whole stack."""
+        with telemetry.span("engine.pin"):
+            v = self._view[tid]
+            state = _lane(v.states, v.lane)
+            with self._pins_lock:
+                self._pins += 1
+        return state, v.cfg, v.gen
+
     def tenant_state(self, tid: str) -> gs.GraphState:
         """Committed snapshot of one tenant (lane extraction)."""
-        with self._locked():
-            t = self._tenants[tid]
-            return _lane(self._groups[t.cfg].states, t.lane)
+        v = self._view[tid]
+        return _lane(v.states, v.lane)
 
     def tenant_cfg(self, tid: str) -> gs.GraphConfig:
-        with self._locked():
-            return self._tenants[tid].cfg
+        return self._view[tid].cfg
 
     def tenant_gen(self, tid: str) -> int:
-        with self._locked():
-            return self._tenants[tid].gen
+        return self._view[tid].gen
 
     def wait_for_gen(self, tid: str, gen: int,
                      timeout: float | None = None) -> int:
@@ -378,8 +439,7 @@ class TenantEngine:
             return
         if live == list(range(len(group.lanes))):
             return
-        idx = jnp.asarray(np.asarray(live, np.int32))
-        group.states = jax.tree.map(lambda a: a[idx], group.states)
+        group.states = _take(group.states, _rows(live))
         for new_lane, old_lane in enumerate(live):
             self._tenants[group.lanes[old_lane]].lane = new_lane
         group.lanes = [group.lanes[i] for i in live]
@@ -459,6 +519,7 @@ class TenantEngine:
             self.flush_count += 1
             for name, n in tiers.items():
                 self.lane_tier_steps[name] += n
+            self._publish()
             self._commit_cv.notify_all()
         telemetry.event("engine.wave", lanes=len(requests),
                         lane_steps=sum(tiers.values()),
@@ -484,8 +545,7 @@ class TenantEngine:
         if whole:
             cur = group.states
         else:
-            lidx = jnp.asarray(np.asarray(lanes, np.int32))
-            cur = jax.tree.map(lambda a: a[lidx], group.states)
+            cur = _take(group.states, _rows(lanes))
         n_rows = len(works)
         xfers: List[tuple] = []       # [(ok [tb,K,B], ovf, tier [tb,K])]
         # --- rounds of vmapped dispatches (async; no host sync) -------
@@ -539,13 +599,9 @@ class TenantEngine:
                 if whole and len(fast) == n_rows:
                     group.states = cur
                 else:
-                    frows = jnp.asarray(np.asarray([w.row for w in fast],
-                                                   np.int32))
-                    flanes = jnp.asarray(np.asarray(
-                        [w.t.lane for w in fast], np.int32))
-                    group.states = jax.tree.map(
-                        lambda g, c: g.at[flanes].set(c[frows]),
-                        group.states, cur)
+                    group.states = _put(
+                        group.states, _rows([w.t.lane for w in fast]),
+                        cur, _rows([w.row for w in fast]))
             # --- oracle-cadence compaction (post-chunk tombstone check)
             for w, work_tomb in zip(works, tomb):
                 if w.error is not None or w.compacted_solo:
@@ -571,10 +627,7 @@ class TenantEngine:
         if full:
             sub = cur
         else:
-            ridx = rows + [rows[0]] * (tb - len(rows))
-            sub = jax.tree.map(
-                lambda a: a[jnp.asarray(np.asarray(ridx, np.int32))],
-                cur)
+            sub = _take(cur, _rows(rows + [rows[0]] * (tb - len(rows))))
         pk = np.full((tb, k, b), dynamic.NOP, np.int32)
         pu = np.zeros((tb, k, b), np.int32)
         pv = np.zeros((tb, k, b), np.int32)
@@ -587,11 +640,9 @@ class TenantEngine:
         if full:
             cur = new_states
         else:
-            sidx = jnp.asarray(np.asarray(rows, np.int32))
-            keep = new_states if len(ws) == tb else jax.tree.map(
-                lambda n: n[:len(ws)], new_states)
-            cur = jax.tree.map(lambda c, n: c.at[sidx].set(n),
-                               cur, keep)
+            # padding rows scatter past the end of `cur`: dropped
+            cur = _put(cur, _rows(rows + [n_rows] * (tb - len(rows))),
+                       new_states, _rows(range(tb)))
         xi = len(xfers)
         xfers.append((ok, ovf, rstats.tier))
         for i, w in enumerate(ws):
@@ -701,10 +752,7 @@ class TenantEngine:
                     lanes = [r[0].lane for r in sub]
                     while len(lanes) < tb:
                         lanes.append(lanes[0])
-                    states = jax.tree.map(
-                        lambda a: a[jnp.asarray(np.asarray(lanes,
-                                                           np.int32))],
-                        group.states)
+                    states = _take(group.states, _rows(lanes))
                     pu = np.zeros((tb, q), np.int32)
                     pv = np.zeros((tb, q), np.int32)
                     for r, (t, uu, vv) in enumerate(sub):
@@ -745,6 +793,7 @@ class TenantEngine:
                 "compile_count": self.compile_count,
                 "compile_bound": self.compile_bound,
                 "query_shapes": len(self._query_compiled),
+                "pins": self._pins,
                 "occupancy": self.occupancy(),
                 "tenant_batches": list(self._tenant_batches),
             }

@@ -66,8 +66,8 @@ class _TenantHandle:
 class _TenantSession:
     """The ``SCCService`` surface of ONE tenant, as seen by
     :class:`repro.api.GraphClient` and :class:`repro.core.broker.QueryBroker`
-    (which need exactly: ``_apply_ops``, ``state``, ``cfg``, ``gen``,
-    ``wait_for_gen``, ``stats``)."""
+    (which need exactly: ``_apply_ops``, ``pin``, ``state``, ``cfg``,
+    ``gen``, ``wait_for_gen``, ``stats``)."""
 
     def __init__(self, service: "MultiTenantService", tid: str):
         self._mts = service
@@ -83,9 +83,14 @@ class _TenantSession:
 
     @property
     def state(self) -> gs.GraphState:
-        """The tenant's committed lane (snapshot-consistent: lanes only
-        move at flush commit, under the engine lock)."""
+        """The tenant's committed lane, from the engine's published read
+        view (a view only changes at the end of a commit)."""
         return self._mts._tenant_state(self.tid)
+
+    def pin(self):
+        """``(state, cfg, gen)`` of the tenant's last commit, from one
+        published view and without the engine lock: the broker's pin."""
+        return self._mts._pin(self.tid)
 
     @property
     def gen(self) -> int:
@@ -365,24 +370,41 @@ class MultiTenantService:
 
     # ------------------------------------------------------------ queries
 
-    def _tenant_state(self, tid: str) -> gs.GraphState:
+    def _resident(self, tid: str, read):
+        """``read(tid)`` on the engine's published view; the service lock
+        is taken only to rehydrate an evicted tenant first."""
+        if self._tenants[tid].resident:
+            try:
+                return read(tid)
+            except KeyError:            # evicted since the check
+                pass
         with self._lock:
             self._ensure_resident(self._tenants[tid])
-        return self._engine.tenant_state(tid)
+            return read(tid)
+
+    def _pin(self, tid: str):
+        return self._resident(tid, self._engine.pin)
+
+    def _tenant_state(self, tid: str) -> gs.GraphState:
+        return self._resident(tid, self._engine.tenant_state)
+
+    def _parked_or(self, tid: str, read, parked: str):
+        """``read(tid)`` on the engine's published view for a resident
+        tenant, else the value its eviction parked."""
+        h = self._tenants[tid]
+        if h.resident:
+            try:
+                return read(tid)
+            except KeyError:            # evicted since the check
+                pass
+        with self._lock:
+            return read(tid) if h.resident else getattr(h, parked)
 
     def _tenant_cfg(self, tid: str) -> gs.GraphConfig:
-        with self._lock:
-            h = self._tenants[tid]
-            if not h.resident:
-                return h.parked_cfg
-        return self._engine.tenant_cfg(tid)
+        return self._parked_or(tid, self._engine.tenant_cfg, "parked_cfg")
 
     def tenant_gen(self, tid: str) -> int:
-        with self._lock:
-            h = self._tenants[tid]
-            if not h.resident:
-                return h.parked_gen
-        return self._engine.tenant_gen(tid)
+        return self._parked_or(tid, self._engine.tenant_gen, "parked_gen")
 
     def same_scc_many(self, items):
         """Cross-tenant vmapped SameSCC (``[(tid, u, v), ...]``) -- the

@@ -113,6 +113,7 @@ def test_request_id_reaches_broker_and_queue():
                              flush_deadline_s=0.0)
     tid = mts.create_tenant()
     client = GraphClient(mts.session(tid))
+    pins0 = mts.engine.stats()["pins"]
     m = mark()
     client.submit_many([AddEdge(0, 1), AddEdge(1, 0), SameSCC(0, 1)])
     client.close()
@@ -129,14 +130,29 @@ def test_request_id_reaches_broker_and_queue():
         assert [r.req for r in by[name]] == [upd.req], name
     for name in ("broker.flush", "broker.pin", "query.same_scc"):
         assert [r.req for r in by[name]] == [rd.req], name
+    # a read pins the engine's published view and never takes its lock
     assert by["engine.lock_wait"] and \
-        {r.req for r in by["engine.lock_wait"]} <= {upd.req, rd.req}
+        {r.req for r in by["engine.lock_wait"]} == {upd.req}
     (wave,) = by["engine.wave"]
     assert wave.attrs["lanes"] == 1 and wave.attrs["lane_steps"] == 1
     assert wave.attrs["tier_skipped"] + wave.attrs["tier_dense"] + \
         wave.attrs["tier_compact"] + wave.attrs["tier_full"] == 1
     (pin,) = by["broker.pin"]
     assert pin.parent == by["broker.flush"][0].id
+    (engine_pin,) = by["engine.pin"]
+    assert engine_pin.parent == pin.id and engine_pin.req == rd.req
+    assert mts.engine.stats()["pins"] - pins0 == len(by["broker.pin"])
+
+
+def test_service_pin_is_state_cfg_gen():
+    """``SCCService.pin()`` gives the three values a broker flush used to
+    read one by one, the generation as a host int."""
+    svc = SCCService(tiny_cfg(), buckets=(8,), scan_lengths=(1,))
+    for c in (cycle_ops(5), rand_chunk(np.random.default_rng(2), 7)):
+        svc._apply_chunk(*c)
+        st, cfg, gen = svc.pin()
+        assert st is svc.state and cfg == svc.cfg
+        assert type(gen) is int and gen == svc.gen == int(st.gen) > 0
 
 
 def test_repair_step_events_match_stats_step_for_step():

@@ -9,6 +9,7 @@ grow-and-replay fallback, and across an evict/rehydrate round trip
 through the PR-6 durable store.  The admission queue's backpressure and
 flush-trigger behaviour is pinned separately at the queue layer.
 """
+import sys
 import threading
 import time
 
@@ -163,6 +164,196 @@ def test_engine_compile_bound():
     # 3 tenants split as tb=2 + tb=1 over one bucket/scan/cfg
     assert eng.compile_count == 2
     assert eng.compile_count <= eng.compile_bound == 2
+
+
+def test_pin_reads_committed_view_while_lock_is_held():
+    """A pin never takes the engine lock: with another thread holding it
+    and a wave queued behind it, a pin returns the last committed
+    (state, gen) at once; after the wave, the next pin sees the wave."""
+    cfg = tiny_cfg()
+    eng = TenantEngine(tenant_batches=(1, 2), **ENGINE_KNOBS)
+    oracle = oracle_for(cfg)
+    eng.create_tenant("a", cfg)
+    eng.create_tenant("b", cfg)
+    rng = np.random.default_rng(13)
+    boot = (np.full(NV, dynamic.ADD_VERTEX, np.int32),
+            np.arange(NV, dtype=np.int32), np.arange(NV, dtype=np.int32))
+    eng.apply_chunks([("a", *boot)])
+    oracle._apply_ops(*boot)
+    committed = (np.asarray(oracle.state.ccid), int(oracle.gen))
+    held, release = threading.Event(), threading.Event()
+
+    def hold():
+        with eng._lock:
+            held.set()
+            release.wait(30)
+
+    chunk = rand_chunk(rng, 12)
+    holder = threading.Thread(target=hold)
+    writer = threading.Thread(
+        target=lambda: eng.apply_chunks([("a", *chunk)]))
+    got = {}
+    reader = threading.Thread(target=lambda: got.update(
+        pin=eng.pin("a"), gen=eng.tenant_gen("a"),
+        cfg=eng.tenant_cfg("a")))
+    holder.start()
+    assert held.wait(10)
+    writer.start()
+    reader.start()
+    reader.join(5)
+    try:
+        assert not reader.is_alive(), "a pin waited for the engine lock"
+    finally:
+        release.set()
+        holder.join(10)
+        writer.join(60)
+    state, pcfg, gen = got["pin"]
+    assert (gen, got["gen"]) == (committed[1], committed[1])
+    assert pcfg == got["cfg"] == cfg and isinstance(gen, int)
+    assert np.array_equal(np.asarray(state.ccid), committed[0])
+    oracle._apply_ops(*chunk)
+    state, pcfg, gen = eng.pin("a")
+    assert gen == int(oracle.gen) > committed[1]
+    assert np.array_equal(np.asarray(state.ccid),
+                          np.asarray(oracle.state.ccid))
+    assert eng.stats()["pins"] == 2
+
+
+def test_concurrent_reads_match_oracle_at_their_generation():
+    """Reader threads over typed clients race writer threads over a few
+    tiny tenants: every answer equals the oracle replay of that tenant
+    at the generation the answer carries."""
+    from repro.api import CommunityOf, GraphClient, SameSCC
+    from repro.core.service import community_of_on, same_scc_on
+
+    cfg = tiny_cfg()
+    mts = MultiTenantService(cfg, tenant_batches=(1, 2, 3),
+                             coalesce_ops=64, flush_deadline_s=0.001,
+                             **ENGINE_KNOBS)
+    tids = [mts.create_tenant() for _ in range(3)]
+    acks = {tid: [] for tid in tids}         # (chunk, ok, gen) in order
+    answers = []                             # (tid, op, value, gen)
+    errors, flushes = [], []
+    go, done = threading.Event(), threading.Event()
+    first_pass = threading.Barrier(3, action=go.set)
+
+    def write(i, tid):
+        rng = np.random.default_rng(100 + i)
+        sess = mts.session(tid)
+        go.wait(60)                 # every reader has read gen 0
+        try:
+            ids = np.arange(NV, dtype=np.int32)
+            chunk = (np.full(NV, dynamic.ADD_VERTEX, np.int32), ids, ids)
+            for _ in range(7):
+                ok, gen = sess._apply_ops(*chunk)
+                acks[tid].append((chunk, np.asarray(ok), gen))
+                chunk = rand_chunk(rng, int(rng.integers(3, 9)))
+        except Exception as e:               # surfaced below
+            errors.append(e)
+
+    def read(i):
+        rng = np.random.default_rng(200 + i)
+        clients = {tid: GraphClient(mts.session(tid)) for tid in tids}
+
+        def one_pass():
+            for tid in tids:
+                pts = rng.integers(0, NV, (4, 2))
+                ops = [SameSCC(int(a), int(b)) for a, b in pts] + \
+                      [CommunityOf(int(a)) for a, _ in pts]
+                for op, r in zip(ops, clients[tid].submit_many(ops)):
+                    answers.append((tid, op, r.value, r.gen))
+
+        try:
+            one_pass()
+            first_pass.wait(60)
+            while not done.wait(0.002):
+                one_pass()
+            one_pass()              # and every final generation
+        except Exception as e:
+            errors.append(e)
+        finally:
+            flushes.append(sum(c.broker.flushes for c in clients.values()))
+            for c in clients.values():
+                c.close()
+
+    writers = [threading.Thread(target=write, args=(i, tid))
+               for i, tid in enumerate(tids)]
+    readers = [threading.Thread(target=read, args=(i,)) for i in range(3)]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-3)
+    try:
+        for th in readers + writers:
+            th.start()
+        for th in writers:
+            th.join(120)
+        done.set()
+        for th in readers:
+            th.join(60)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(th.is_alive() for th in writers + readers)
+    mts.close()
+    assert not errors, errors
+    # one pin per broker flush, none lost to a racing increment
+    assert mts.engine.stats()["pins"] == sum(flushes)
+    for tid in tids:
+        oracle = oracle_for(cfg)
+        at = {0: (oracle.state, oracle.cfg)}
+        for chunk, ok, gen in acks[tid]:
+            want_ok, want_gen = oracle._apply_ops(*chunk)
+            assert np.array_equal(ok, np.asarray(want_ok)) and \
+                gen == want_gen, tid
+            at[gen] = (oracle.state, oracle.cfg)
+        mine = [a for a in answers if a[0] == tid]
+        gens = {a[3] for a in mine}
+        assert gens <= set(at), f"{tid}: answers at unknown gens"
+        assert len(gens) > 1, f"{tid}: reads saw no commit"
+        ids = np.arange(NV, dtype=np.int32)
+        want = {}                           # gen -> (same [NV, NV], comm)
+        for gen in gens:
+            st, scfg = at[gen]
+            same = same_scc_on(st, scfg, np.repeat(ids, NV), np.tile(ids, NV))
+            want[gen] = (same.reshape(NV, NV), community_of_on(st, scfg, ids))
+        for _, op, value, gen in mine:
+            if isinstance(op, SameSCC):
+                assert value == want[gen][0][op.u, op.v], (tid, op, gen)
+            else:
+                assert value == want[gen][1][op.u], (tid, op, gen)
+
+
+def test_repeated_pins_add_no_jit_entry():
+    """The lane slice is one jitted program per stack shape: after the
+    first pin of a group, pins of any lane, across commits, compile
+    nothing; a new capacity group adds at most one entry."""
+    from repro.tenancy import engine as engine_mod
+
+    cfg = tiny_cfg()
+    eng = TenantEngine(tenant_batches=(1, 2, 4), **ENGINE_KNOBS)
+    tids = ("a", "b", "c")
+    for tid in tids:
+        eng.create_tenant(tid, cfg)
+    rng = np.random.default_rng(17)
+
+    def wave():
+        eng.apply_chunks([(tid, *rand_chunk(rng, 6)) for tid in tids])
+
+    wave()                          # the wave's own gathers compile here
+    eng.pin("a")
+    entries = engine_mod._take._cache_size()
+    for _ in range(3):
+        wave()
+        for tid in tids:
+            eng.pin(tid)
+    assert engine_mod._take._cache_size() == entries
+    eng.create_tenant("big", tiny_cfg(edge_capacity=128))
+    eng.pin("big")
+    grown = engine_mod._take._cache_size()
+    assert grown <= entries + 1
+    for _ in range(3):
+        eng.pin("big")
+        eng.pin("b")
+    assert engine_mod._take._cache_size() == grown
+    assert eng.stats()["pins"] == 1 + 9 + 1 + 6
 
 
 # -------------------------------------------------------------- service
