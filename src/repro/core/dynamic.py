@@ -51,6 +51,15 @@ FW/BW fixpoint and of the chosen tier are returned as
 (``p1_remove_vertex`` .. ``p4_add_edge``, ``p5_reach``, ``p5_tier``), so
 a profile attributes device operations to phases.
 
+**Whole-lane dense.**  When ``dense_capacity >= n_vertices`` every region
+fits the dense tier, so the step compiles phase 5 as one dense closure of
+the whole live graph (``p5_dense``): no FW/BW sweep and no sparse tier is
+traced.  Labels outside the region were already the minimum member ids,
+so relabelling every live vertex gives the tiered path's labels bit for
+bit.  Under ``vmap`` a ``lax.cond`` on a per-lane predicate runs every
+branch, so a tenant lane of a small dense graph would otherwise pay the
+sweeps and every tier on every step; here it pays one MXU closure.
+
 Two step-level fusions keep the *update-heavy* path fast (the paper's
 Fig 4/5 regime, where most ops do not change SCC structure):
 
@@ -120,6 +129,20 @@ def _first_claim(cand, target, nv, b):
     return cand & (claims[target] == idx)
 
 
+def _dense_labels(src, dst, live, region, capacity: int,
+                  cfg: gs.GraphConfig):
+    """Masked SCC labels of ``region`` from the boolean closure of its
+    ``capacity``-square adjacency, and the squarings it took.  The
+    closure runs through the injected reach_blockmm boolean mat-mul
+    (Pallas on TPU, interpret-mode validation on CPU, jnp oracle under
+    impl='xla')."""
+    def matmul(a, b):
+        return reach_blockmm.bool_matmul(a, b, impl=cfg.dense_matmul_impl)
+    lab, _fits = scc.scc_dense_region(src, dst, live, region, capacity,
+                                      matmul=matmul)
+    return lab, jnp.int32(scc.closure_rounds(capacity))
+
+
 def _repair_tiers(src, dst, live, region, region_v, region_e,
                   cfg: gs.GraphConfig):
     """Masked static-SCC labels of ``region`` by the smallest tier it fits.
@@ -174,20 +197,12 @@ def _repair_tiers(src, dst, live, region, region_v, region_e,
                      above=dispatch):
             return jax.lax.cond(fits, below, above, None)
 
-    # (1) dense MXU: small enough to densify; the adjacency closure
-    # runs through the injected reach_blockmm boolean mat-mul (Pallas
-    # on TPU, interpret-mode validation on CPU, jnp oracle under
-    # impl='xla').
+    # (1) dense MXU: small enough to densify
     if cfg.dense_capacity > 0:
         def repair_dense(_):
-            def matmul(a, b):
-                return reach_blockmm.bool_matmul(
-                    a, b, impl=cfg.dense_matmul_impl)
-            lab, _fits = scc.scc_dense_region(src, dst, live, region,
-                                              cfg.dense_capacity,
-                                              matmul=matmul)
-            return (lab, jnp.int32(TIER_DENSE),
-                    jnp.int32(scc.closure_rounds(cfg.dense_capacity)))
+            lab, rounds = _dense_labels(src, dst, live, region,
+                                        cfg.dense_capacity, cfg)
+            return lab, jnp.int32(TIER_DENSE), rounds
 
         fits_dense = region_v <= cfg.dense_capacity
 
@@ -294,7 +309,20 @@ def _apply_batch_impl(state: gs.GraphState, ops: OpBatch,
     straddle = inserted & (ccid[jnp.clip(ops.u, 0, nv - 1)] !=
                            ccid[jnp.clip(ops.v, 0, nv - 1)])
 
-    def run_repair(_):
+    def run_repair_dense_lane(_):
+        # every live vertex is relabelled by one closure of the live graph
+        with jax.named_scope("p5_dense"):
+            new_lab, scc_rounds = _dense_labels(src, dst, live, v_alive,
+                                                nv, cfg)
+        repair = RepairStats(tier=jnp.int32(TIER_DENSE),
+                             region_vertices=jnp.sum(v_alive).astype(
+                                 jnp.int32),
+                             region_edges=jnp.sum(live).astype(jnp.int32),
+                             reach_rounds=jnp.int32(0),
+                             scc_rounds=scc_rounds)
+        return jnp.where(v_alive, new_lab, ccid), repair
+
+    def run_repair_tiered(_):
         with jax.named_scope("p5_reach"):
             seed_f = jnp.zeros((nv,), jnp.bool_).at[
                 jnp.where(straddle, ops.v, nv)].set(True, mode="drop")
@@ -324,6 +352,10 @@ def _apply_batch_impl(state: gs.GraphState, ops: OpBatch,
                              reach_rounds=reach_rounds,
                              scc_rounds=scc_rounds)
         return jnp.where(region, new_lab, ccid), repair
+
+    # a lane whose every region fits the dense tier compiles no other tier
+    run_repair = (run_repair_dense_lane if cfg.dense_capacity >= nv
+                  else run_repair_tiered)
 
     if cfg.repair_gate:
         # In-graph repair gate: the region is M_del ∪ (FW ∩ BW), FW/BW are

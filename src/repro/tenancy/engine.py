@@ -41,6 +41,10 @@ Design rules (all load-bearing for the differential oracle test):
   ``fill_stats`` over the flushed lanes (amortized into the flush's
   single host sync) and compacts over-threshold lanes through the same
   throwaway-service path.
+* **Bulk provisioning.**  :meth:`create_tenants` stacks N built
+  tenants into their groups with one program and one publish;
+  :meth:`create_tenant` appends one lane (a stack copy each) and stays
+  the path of single adds and rehydration.
 * **Reads pin a published view.**  Every mutation ends by publishing an
   immutable ``tid -> (stack, lane, gen, cfg)`` map; :meth:`pin` reads it
   without the engine lock, so a read never waits behind a wave.
@@ -128,6 +132,16 @@ def _put(tree, idx, src, rows):
     index past the end is dropped (a padding row)."""
     return jax.tree.map(lambda a, b: a.at[idx].set(b[rows], mode="drop"),
                         tree, src)
+
+
+@jax.jit
+def _append_lanes(stack, lanes):
+    """``stack`` (None for a new group) with ``lanes`` appended as new
+    rows: one program however many lanes arrive."""
+    new = _stack(lanes)
+    if stack is None:
+        return new
+    return jax.tree.map(lambda a, b: jnp.concatenate([a, b]), stack, new)
 
 
 def _rows(idx) -> np.ndarray:
@@ -229,6 +243,7 @@ class TenantEngine:
         self._view: Dict[str, _View] = {}
         self._pins = 0
         self._pins_lock = threading.Lock()
+        self._provisioned = 0         # tenants added by create_tenants
         # compiled-entry registries (update entries are the bounded ones;
         # query/fill-stats entries are separately cached, like the
         # service's query shapes)
@@ -303,6 +318,38 @@ class TenantEngine:
             self._tenants[tid] = _Tenant(
                 tid=tid, cfg=cfg, lane=lane,
                 gen=int(state.gen) if gen is None else int(gen))
+            self._publish()
+
+    def create_tenants(self, tenants: Sequence[Tuple[str, gs.GraphConfig,
+                                                     gs.GraphState]]):
+        """Give each ``(tid, cfg, state)`` a lane, as many
+        :meth:`create_tenant` calls would, at the generation its state
+        carries: every capacity group grows by one stacking program and
+        the read view is published once, inside one ``engine.provision``
+        span.  Provisioning is linear in the tenant count, where
+        appending one lane at a time copies the stack each time."""
+        with self._lock, telemetry.span("engine.provision",
+                                        tenants=len(tenants)):
+            tids = [tid for tid, _, _ in tenants]
+            assert len(set(tids)) == len(tids), "duplicate tenant ids"
+            for tid in tids:
+                assert tid not in self._tenants, f"tenant {tid!r} exists"
+            gens = jax.device_get([st.gen for _, _, st in tenants])
+            by_cfg: Dict[gs.GraphConfig, list] = {}
+            for (tid, cfg, st), gen in zip(tenants, gens):
+                by_cfg.setdefault(cfg, []).append((tid, st, int(gen)))
+            for cfg, rows in by_cfg.items():
+                group = self._groups.get(cfg)
+                if group is None:
+                    group = self._groups[cfg] = _Group(cfg)
+                base = len(group.lanes)
+                group.states = _append_lanes(group.states,
+                                             [st for _, st, _ in rows])
+                for i, (tid, _, gen) in enumerate(rows):
+                    group.lanes.append(tid)
+                    self._tenants[tid] = _Tenant(tid=tid, cfg=cfg,
+                                                 lane=base + i, gen=gen)
+            self._provisioned += len(tenants)
             self._publish()
 
     def remove_tenant(self, tid: str) -> Tuple[gs.GraphState,
@@ -794,6 +841,7 @@ class TenantEngine:
                 "compile_bound": self.compile_bound,
                 "query_shapes": len(self._query_compiled),
                 "pins": self._pins,
+                "provisioned": self._provisioned,
                 "occupancy": self.occupancy(),
                 "tenant_batches": list(self._tenant_batches),
             }

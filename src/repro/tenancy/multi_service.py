@@ -33,6 +33,7 @@ import threading
 import time
 from typing import Dict, Optional
 
+import jax
 import numpy as np
 
 from repro.ckpt import checkpoint, oplog
@@ -167,28 +168,49 @@ class MultiTenantService:
         """Provision a tenant: a fresh empty graph at generation 0 (and,
         under a durable root, its own snapshot+WAL store)."""
         with self._lock:
-            if tid is None:
-                tid = f"t{self._next_tid}"
-                self._next_tid += 1
-            assert tid not in self._tenants, f"tenant {tid!r} exists"
-            tenant_dir = None
-            if self._dir is not None:
-                tenant_dir = os.path.join(self._dir, "tenants", tid)
-            h = _TenantHandle(tid, tenant_dir)
             state = gs.empty(self._boot_cfg)
-            if tenant_dir is not None:
-                os.makedirs(snap_dir(tenant_dir), exist_ok=True)
-                os.makedirs(wal_dir(tenant_dir), exist_ok=True)
-                checkpoint.save_graph_snapshot(
-                    snap_dir(tenant_dir), state,
-                    self._snapshot_meta(self._boot_cfg, 0),
-                    keep=self._snapshot_keep)
-                h.wal = oplog.OpLogWriter(
-                    wal_dir(tenant_dir), sync_every=self._wal_sync_every,
-                    start_gen=0)
+            tid = self._new_handle(tid, state, 0)
             self._engine.create_tenant(tid, self._boot_cfg, state=state)
-            self._tenants[tid] = h
             return tid
+
+    def create_tenants(self, states) -> list:
+        """Provision one tenant per built ``state`` (a graph already
+        loaded and labelled, at the boot config's capacities) at the
+        generation it carries, normally 0: one engine program stacks
+        them all and one publish makes them readable.  Returns the new
+        tenant ids, in the order of ``states``."""
+        states = list(states)
+        gens = [int(g) for g in jax.device_get([st.gen for st in states])]
+        with self._lock:
+            tids = [self._new_handle(None, st, g)
+                    for st, g in zip(states, gens)]
+            self._engine.create_tenants(
+                [(tid, self._boot_cfg, st) for tid, st in zip(tids, states)])
+            return tids
+
+    def _new_handle(self, tid, state: gs.GraphState, gen: int) -> str:
+        """Register a tenant's handle (and, under a durable root, its
+        store: a snapshot of ``state`` at ``gen`` and a WAL from there)."""
+        if tid is None:
+            tid = f"t{self._next_tid}"
+            self._next_tid += 1
+        assert tid not in self._tenants, f"tenant {tid!r} exists"
+        tenant_dir = None
+        if self._dir is not None:
+            tenant_dir = os.path.join(self._dir, "tenants", tid)
+        h = _TenantHandle(tid, tenant_dir)
+        if tenant_dir is not None:
+            os.makedirs(snap_dir(tenant_dir), exist_ok=True)
+            os.makedirs(wal_dir(tenant_dir), exist_ok=True)
+            checkpoint.save_graph_snapshot(
+                snap_dir(tenant_dir), state,
+                self._snapshot_meta(self._boot_cfg, gen),
+                keep=self._snapshot_keep)
+            h.wal = oplog.OpLogWriter(
+                wal_dir(tenant_dir), sync_every=self._wal_sync_every,
+                start_gen=gen)
+        self._tenants[tid] = h
+        return tid
 
     def delete_tenant(self, tid: str):
         """Drop the tenant: lane, handle, and durable store."""

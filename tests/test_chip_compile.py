@@ -5,7 +5,8 @@ described rather than present.  That refuses what interpret mode accepts:
 unsupported reductions, block shapes off the (8, 128) tiling, kernels that
 overflow VMEM, programs that do not fit the chip's 16 GB of HBM.  So each
 case below compiles a Pallas kernel of the main path at the width it runs
-at, or the whole ``update_1m`` step, for one chip of a ``v5e:2x2`` slice.
+at, or a whole update step (``update_1m``, and the tenant engine's
+vmapped whole-lane dense step), for one chip of a ``v5e:2x2`` slice.
 
 Every case forces ``impl='pallas'``: ``jax.default_backend()`` reads CPU
 here, so ``'auto'`` would pick the XLA path and compile no kernel.  The
@@ -136,3 +137,31 @@ def test_update_1m_scan_step_fits_one_chip(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < V5E_HBM_BYTES, total
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_tenant_dense_lane_step_fits_one_chip(one_chip):
+    """The tenant engine's vmapped step at the ego-Gplus tenant widths: 16
+    lanes of 1024 vertex slots and 2^18 edge slots, each repaired by one
+    dense closure through the reach_blockmm kernel, inside one chip's
+    HBM; the kernel's Mosaic calls keep its wrapper's name, which is how
+    a trace finds them.  The probes run on XLA, as ``'auto'`` picks past
+    the probe kernel's 2^16 slots."""
+    cfg = smscc.config(n_vertices=1024, edge_capacity=1 << 18,
+                       dense_capacity=1024, dense_matmul_impl="pallas",
+                       sparse_impl="xla")
+    state = jax.tree.map(
+        lambda s: _sds(one_chip, (16,) + s.shape, s.dtype),
+        jax.eval_shape(lambda: gs.empty(cfg)))
+    lanes = _sds(one_chip, (16, 1, 64), jnp.int32)
+    ops = dynamic.OpBatch(kind=lanes, u=lanes, v=lanes)
+    compiled = _compile(
+        jax.vmap(lambda s, o: dynamic._apply_batch_scan_impl(s, o, cfg)),
+        state, ops)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < V5E_HBM_BYTES, total
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert any(line.lstrip().startswith("%bool_matmul_f32")
+               and "custom-call(" in line for line in text.splitlines())
