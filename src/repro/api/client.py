@@ -446,7 +446,9 @@ class GraphClient:
         that ran through the ``lax.scan`` super-chunk path -- and
         ``repair_skipped_steps`` -- steps the in-graph repair gate proved
         structure-preserving, next to the per-tier
-        ``repair_{dense,compact,full}_steps``), broker (coalesced
+        ``repair_{dense,compact,full}_steps``, and the edge layout the
+        sweeps ran on, ``repair_layout_{sorted,fallback,none}_steps``),
+        broker (coalesced
         flushes, gen waits), and session counters."""
         s = dict(self._svc.stats())
         s.update(self._broker.stats())

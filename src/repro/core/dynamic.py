@@ -42,14 +42,24 @@ to the region, not the table (the other half of locality of repair):
                   round;
   tier 2 full     overflow fallback: scc_static over the full edge table.
 
+Where the sweeps run on the sorted live-edge layout
+(:func:`repro.core.layout.uses_layout`: ``sparse_impl='sorted'``, or
+``'auto'`` on a TPU above the sweep kernel's vertex ceiling), phase 5
+sorts the live edges once (``p5_layout``), runs the FW/BW sweeps and,
+where no smaller tier takes the region, the full tier on that layout,
+and only then the smaller tiers, so the layout is dead while they run.
+A table with more than half its slots live takes the scatter sweeps
+instead, through one runtime ``lax.cond``.
+
 Tier choice is a runtime ``lax.cond`` inside the one compiled step (no
 extra compilations); every tier produces bit-identical labels.  The
-chosen tier, the region's vertex/edge counts and the iterations of the
-FW/BW fixpoint and of the chosen tier are returned as
-:class:`RepairStats` next to the overflow delta, and surfaced by
-``SCCService.stats()``.  Each phase runs under a ``jax.named_scope``
-(``p1_remove_vertex`` .. ``p4_add_edge``, ``p5_reach``, ``p5_tier``), so
-a profile attributes device operations to phases.
+chosen tier, the region's vertex/edge counts, the iterations of the
+FW/BW fixpoint and of the chosen tier, and the layout the sweeps ran on
+are returned as :class:`RepairStats` next to the overflow delta, and
+surfaced by ``SCCService.stats()``.  Each phase runs under a
+``jax.named_scope`` (``p1_remove_vertex`` .. ``p4_add_edge``,
+``p5_layout``, ``p5_reach``, ``p5_tier``), so a profile attributes device
+operations to phases.
 
 **Whole-lane dense.**  When ``dense_capacity >= n_vertices`` every region
 fits the dense tier, so the step compiles phase 5 as one dense closure of
@@ -84,7 +94,7 @@ import jax.numpy as jnp
 
 from repro.core import edge_table as et
 from repro.core import graph_state as gs
-from repro.core import reach, scc
+from repro.core import layout as lay, reach, scc
 from repro.kernels.reach_blockmm import ops as reach_blockmm
 
 ADD_EDGE = 0
@@ -143,25 +153,54 @@ def _dense_labels(src, dst, live, region, capacity: int,
     return lab, jnp.int32(scc.closure_rounds(capacity))
 
 
+def _compact_buckets(cfg: gs.GraphConfig) -> tuple:
+    """The compact tier's edge buckets, () where it is not compiled."""
+    if not 0 < cfg.region_vertex_capacity < cfg.n_vertices:
+        return ()
+    return tuple(b for b in cfg.region_edge_buckets if b < cfg.edge_capacity)
+
+
+def _fits_compact(region_v, region_e, cfg: gs.GraphConfig):
+    buckets = _compact_buckets(cfg)
+    return (region_v <= cfg.region_vertex_capacity) & \
+        (region_e <= buckets[-1])
+
+
+def _below_full(region_v, region_e, cfg: gs.GraphConfig):
+    """bool[]: whether a tier below the full one takes the region."""
+    fits = jnp.bool_(False)
+    if _compact_buckets(cfg):
+        fits = _fits_compact(region_v, region_e, cfg)
+    if cfg.dense_capacity > 0:
+        fits = fits | (region_v <= cfg.dense_capacity)
+    return fits
+
+
+def _full_tier(src, dst, live, region, cfg: gs.GraphConfig, layout=None):
+    return scc.scc_static_rounds(
+        src, dst, live, region, max_outer=cfg.max_outer,
+        max_inner=cfg.max_inner, spec=cfg.label_spec,
+        shortcut=cfg.shortcut, impl=cfg.kernel_impl, layout=layout)
+
+
 def _repair_tiers(src, dst, live, region, region_v, region_e,
-                  cfg: gs.GraphConfig):
+                  cfg: gs.GraphConfig, full=None):
     """Masked static-SCC labels of ``region`` by the smallest tier it fits.
 
     Returns ``(labels, tier, scc_rounds)``: labels valid inside the
     region, the tier code, and the tier's iterations (trim and propagation
     rounds for the sparse tiers, boolean squarings for the dense one).
+    ``full``, where given, is the full tier's ``(labels, rounds)``,
+    computed beforehand on the sorted layout.
     """
     # The region is the same for every tier; each tier is a cheaper
     # execution of the identical masked static-SCC pass.  Tiers nest
     # smallest-first via lax.cond (one compiled program per cfg -- tier
     # choice is a runtime branch, never a recompile).
-    nv = cfg.n_vertices
 
     def repair_full(_):
-        lab, rounds = scc.scc_static_rounds(
-            src, dst, live, region, max_outer=cfg.max_outer,
-            max_inner=cfg.max_inner, spec=cfg.label_spec,
-            shortcut=cfg.shortcut, impl=cfg.sparse_impl)
+        lab, rounds = (full if full is not None
+                       else _full_tier(src, dst, live, region, cfg))
         return lab, jnp.int32(TIER_FULL), rounds
 
     dispatch = repair_full
@@ -170,28 +209,33 @@ def _repair_tiers(src, dst, live, region, region_v, region_e,
     # slots come from the geometric bucket registry; the smallest
     # bucket that holds the region's live edges wins (lax.switch over
     # static shapes).
-    e_buckets = tuple(b for b in cfg.region_edge_buckets
-                      if b < cfg.edge_capacity)
-    if 0 < cfg.region_vertex_capacity < nv and e_buckets:
+    e_buckets = _compact_buckets(cfg)
+    if e_buckets:
         vcap = cfg.region_vertex_capacity
-
-        def compact_branch(ecap):
-            def run(_):
-                lab, _fits, rounds = scc.scc_compact_region(
-                    src, dst, live, region, vcap, ecap,
-                    max_outer=cfg.max_outer, max_inner=cfg.max_inner,
-                    shortcut=cfg.shortcut, impl=cfg.sparse_impl)
-                return lab, jnp.int32(TIER_COMPACT), rounds
-            return run
-
-        branches = [compact_branch(b) for b in e_buckets]
         bucket_idx = jnp.minimum(
             jnp.sum((region_e > jnp.asarray(e_buckets, jnp.int32))
                     .astype(jnp.int32)), len(e_buckets) - 1)
-        fits_compact = (region_v <= vcap) & (region_e <= e_buckets[-1])
+        fits_compact = _fits_compact(region_v, region_e, cfg)
 
         def repair_compact(_):
-            return jax.lax.switch(bucket_idx, branches, None)
+            # one table-wide compaction, into the largest bucket: a
+            # smaller bucket's edge list is its prefix
+            csrc, cdst, celive, ids, valid, _, _ = scc.compact_region(
+                src, dst, live, region, vcap, e_buckets[-1])
+
+            def compact_branch(ecap):
+                def run(_):
+                    lab, rounds = scc.compact_labels(
+                        csrc[:ecap], cdst[:ecap], celive[:ecap], ids, valid,
+                        cfg.n_vertices, max_outer=cfg.max_outer,
+                        max_inner=cfg.max_inner, shortcut=cfg.shortcut,
+                        impl=cfg.kernel_impl)
+                    return lab, jnp.int32(TIER_COMPACT), rounds
+                return run
+
+            return jax.lax.switch(bucket_idx,
+                                  [compact_branch(b) for b in e_buckets],
+                                  None)
 
         def dispatch(_, fits=fits_compact, below=repair_compact,
                      above=dispatch):
@@ -262,7 +306,7 @@ def _apply_batch_impl(state: gs.GraphState, ops: OpBatch,
             v_alive[jnp.clip(ops.v, 0, nv - 1)]
         edges, removed = et.remove(edges, ops.u, ops.v, cfg.max_probes,
                                    enable=is_reme & ends_ok,
-                                   impl=cfg.sparse_impl)
+                                   impl=cfg.kernel_impl)
         ok = jnp.where(removed, True, ok)
         same_class = ccid[jnp.clip(ops.u, 0, nv - 1)] == \
             ccid[jnp.clip(ops.v, 0, nv - 1)]
@@ -291,7 +335,12 @@ def _apply_batch_impl(state: gs.GraphState, ops: OpBatch,
         enable = is_adde & ends_ok
         edges, inserted, dropped = et.insert(edges, ops.u, ops.v,
                                              cfg.max_probes, enable=enable,
-                                             impl=cfg.sparse_impl)
+                                             impl=cfg.kernel_impl)
+        # a key whose probe window earlier keys filled goes in by laying
+        # its chain out again, rather than growing the table
+        edges, rescued = et.reorder_chain(edges, ops.u, ops.v, dropped,
+                                          cfg.max_probes)
+        inserted, dropped = inserted | rescued, dropped & ~rescued
         ok = jnp.where(inserted, True, ok)
         # overflow accounting straight from the table's own
         # probe-exhaustion report -- the host must grow the table and
@@ -319,8 +368,59 @@ def _apply_batch_impl(state: gs.GraphState, ops: OpBatch,
                                  jnp.int32),
                              region_edges=jnp.sum(live).astype(jnp.int32),
                              reach_rounds=jnp.int32(0),
-                             scc_rounds=scc_rounds)
+                             scc_rounds=scc_rounds,
+                             layout=jnp.int32(lay.LAYOUT_NONE))
         return jnp.where(v_alive, new_lab, ccid), repair
+
+    def fw_bw(seed_f, seed_b, layout):
+        if layout is not None:
+            sweeps = lay.fused_reach if cfg.fuse_fwbw else lay.reach_pair
+            return sweeps(layout, seed_f, seed_b, v_alive, cfg.max_inner,
+                          spec=cfg.label_spec)
+        if cfg.fuse_fwbw:
+            return reach.fused_fw_bw_reach(
+                src, dst, live, seed_f, seed_b, v_alive, cfg.max_inner,
+                spec=cfg.label_spec, impl=cfg.kernel_impl)
+        fw, r_fw = reach.forward_reach(
+            src, dst, live, seed_f, v_alive, cfg.max_inner,
+            spec=cfg.label_spec, impl=cfg.kernel_impl)
+        bw, r_bw = reach.backward_reach(
+            src, dst, live, seed_b, v_alive, cfg.max_inner,
+            spec=cfg.label_spec, impl=cfg.kernel_impl)
+        return fw, bw, r_fw + r_bw
+
+    def sweep(seed_f, seed_b, layout=None):
+        """The FW/BW sweeps on ``layout`` (None: the scatter sweeps) and
+        the region they bound."""
+        with jax.named_scope("p5_reach"):
+            fw, bw, reach_rounds = fw_bw(seed_f, seed_b, layout)
+            region = (m_del | (fw & bw)) & v_alive
+            region_v = jnp.sum(region).astype(jnp.int32)
+            if layout is not None:
+                region_e = lay.count_within(region, layout)
+            else:
+                region_e = jnp.sum(live & region[src] & region[dst]
+                                   ).astype(jnp.int32)
+        return region, region_v, region_e, reach_rounds
+
+    def sweep_and_full(seed_f, seed_b, layout):
+        """:func:`sweep`, then the full tier's labels on the same edge
+        engine where no smaller tier takes the region."""
+        region, region_v, region_e, reach_rounds = sweep(seed_f, seed_b,
+                                                         layout)
+        with jax.named_scope("p5_tier"):
+            full = jax.lax.cond(
+                _below_full(region_v, region_e, cfg),
+                lambda _: (jnp.full((nv,), scc.INT32_MAX, jnp.int32),
+                           jnp.int32(0)),
+                lambda _: _full_tier(src, dst, live, region, cfg,
+                                     layout=layout), None)
+        return region, region_v, region_e, reach_rounds, full
+
+    def on_layout(seed_f, seed_b):
+        with jax.named_scope("p5_layout"):
+            layout = lay.build(src, dst, live, nv)
+        return sweep_and_full(seed_f, seed_b, layout)
 
     def run_repair_tiered(_):
         with jax.named_scope("p5_reach"):
@@ -328,29 +428,26 @@ def _apply_batch_impl(state: gs.GraphState, ops: OpBatch,
                 jnp.where(straddle, ops.v, nv)].set(True, mode="drop")
             seed_b = jnp.zeros((nv,), jnp.bool_).at[
                 jnp.where(straddle, ops.u, nv)].set(True, mode="drop")
-            if cfg.fuse_fwbw:
-                fw, bw, reach_rounds = reach.fused_fw_bw_reach(
-                    src, dst, live, seed_f, seed_b, v_alive, cfg.max_inner,
-                    spec=cfg.label_spec, impl=cfg.sparse_impl)
-            else:
-                fw, r_fw = reach.forward_reach(
-                    src, dst, live, seed_f, v_alive, cfg.max_inner,
-                    spec=cfg.label_spec, impl=cfg.sparse_impl)
-                bw, r_bw = reach.backward_reach(
-                    src, dst, live, seed_b, v_alive, cfg.max_inner,
-                    spec=cfg.label_spec, impl=cfg.sparse_impl)
-                reach_rounds = r_fw + r_bw
-            region = (m_del | (fw & bw)) & v_alive
-            region_v = jnp.sum(region).astype(jnp.int32)
-            region_e = jnp.sum(live & region[src] & region[dst]
-                               ).astype(jnp.int32)
+        if lay.uses_layout(cfg.sparse_impl, nv):
+            # one sorted layout serves the sweeps and the full tier; the
+            # smaller tiers run after it, so their table-wide compaction
+            # never holds it
+            fits = lay.fits(live)
+            region, region_v, region_e, reach_rounds, full = jax.lax.cond(
+                fits, lambda _: on_layout(seed_f, seed_b),
+                lambda _: sweep_and_full(seed_f, seed_b, None), None)
+            layout_code = jnp.where(fits, lay.LAYOUT_SORTED,
+                                    lay.LAYOUT_FALLBACK).astype(jnp.int32)
+        else:
+            region, region_v, region_e, reach_rounds = sweep(seed_f, seed_b)
+            full, layout_code = None, jnp.int32(lay.LAYOUT_NONE)
         with jax.named_scope("p5_tier"):
             new_lab, tier, scc_rounds = _repair_tiers(
-                src, dst, live, region, region_v, region_e, cfg)
+                src, dst, live, region, region_v, region_e, cfg, full)
         repair = RepairStats(tier=tier, region_vertices=region_v,
                              region_edges=region_e,
                              reach_rounds=reach_rounds,
-                             scc_rounds=scc_rounds)
+                             scc_rounds=scc_rounds, layout=layout_code)
         return jnp.where(region, new_lab, ccid), repair
 
     # a lane whose every region fits the dense tier compiles no other tier
@@ -480,6 +577,6 @@ def recompute(state: gs.GraphState, cfg: gs.GraphConfig) -> gs.GraphState:
     lab = scc.scc_static(src, dst, live, state.v_alive,
                          max_outer=cfg.max_outer, max_inner=cfg.max_inner,
                          spec=cfg.label_spec, shortcut=cfg.shortcut,
-                         impl=cfg.sparse_impl)
+                         impl=cfg.kernel_impl)
     ccid = jnp.where(state.v_alive, lab, cfg.n_vertices)
     return gs.recount_ccs(state._replace(ccid=ccid, gen=state.gen + 1))

@@ -28,6 +28,9 @@ from repro.kernels.hash_probe import ops as hash_probe
 EMPTY = jnp.int8(0)
 LIVE = jnp.int8(1)
 TOMB = jnp.int8(2)
+# tables below this many slots grow when a key finds its probe window full
+# (:func:`reorder_chain` places it only in larger ones)
+RELAYOUT_MIN_CAP = 1 << 20
 
 
 class EdgeTable(NamedTuple):
@@ -173,6 +176,63 @@ def insert(table: EdgeTable, u: jax.Array, v: jax.Array, max_probes: int,
     table, placed, _ = jax.lax.fori_loop(
         0, max_probes, round_body, (table, placed, probe))
     return table, placed, want & ~placed
+
+
+def reorder_chain(table: EdgeTable, u, v, failed, max_probes: int):
+    """Place the first key :func:`insert` reported ``failed`` (all
+    ``max_probes`` slots from its home LIVE); returns ``(table, placed)``,
+    ``placed`` true on that lane where it went in.
+
+    At the half load the engine keeps, such a run is a clustering
+    accident: first-come placement has pushed keys of earlier homes across
+    the new key's window.  The key's probe chain -- the slots between the
+    EMPTY slot before its home and the first EMPTY slot from it -- is laid
+    out again with the new key, in home order: each key at the first slot
+    from its home that the keys of smaller homes leave free.  That order
+    gives the chain its least displacement; every key still lies within
+    its probe bound with no EMPTY slot between its home and it, so lookups
+    find it, and the chain's tombstones go.  No key outside the chain
+    probes through it.  The lane stays failed where the chain leaves the
+    window of ``4 * max_probes`` slots around the home (or the table's
+    ends), or a key would land ``max_probes`` or more from its home.  One
+    lane a call: two such accidents in one batch are rarer still.  Tables
+    below :data:`RELAYOUT_MIN_CAP` slots keep growing instead.
+    """
+    cap = table.src.shape[0]
+    if cap < RELAYOUT_MIN_CAP:
+        # a table of at most 9 MB grows cheaply; the tenant engine vmaps
+        # such tables, where this lane's sort and window would add ~2 MB of
+        # resident program code to every step program
+        return table, jnp.zeros_like(failed)
+    w = min(4 * max_probes, cap)
+    off = jnp.arange(w, dtype=jnp.int32)
+    entry = jnp.arange(w + 1, dtype=jnp.int32)
+    lane = jnp.argmax(failed)
+    h = _hash(u[lane], v[lane], cap)
+    start = jnp.clip(h - w // 2, 0, cap - w)
+    c = h - start  # the home's offset in the window
+    s, d, st = (jax.lax.dynamic_slice(x, (start,), (w,)) for x in table)
+    empty = st == EMPTY
+    lo = jnp.max(jnp.where(empty & (off < c), off, -1))
+    hi = jnp.min(jnp.where(empty & (off >= c), off, w))
+    keys = jnp.concatenate([(st == LIVE) & (off > lo) & (off < hi),
+                            jnp.ones((1,), jnp.bool_)])
+    home = jnp.concatenate([_hash(s, d, cap) - start, c[None]])
+    home, ks, kd = jax.lax.sort(
+        (jnp.where(keys, home, 2 * w), jnp.concatenate([s, u[lane][None]]),
+         jnp.concatenate([d, v[lane][None]])), num_keys=1)
+    # home order: each key at max(its home, the previous key's slot + 1)
+    pos = entry + jax.lax.cummax(home - entry)
+    used = entry < jnp.sum(keys)
+    fits = failed[lane] & (lo >= 0) & (hi < w) & jnp.all(
+        ~used | ((pos <= hi) & (pos - home < max_probes)))
+    to = jnp.where(used & fits, pos, w)
+    chain = fits & (off > lo) & (off <= hi)
+    window = (s.at[to].set(ks, mode="drop"), d.at[to].set(kd, mode="drop"),
+              jnp.where(chain, EMPTY, st).at[to].set(LIVE, mode="drop"))
+    table = EdgeTable(*(jax.lax.dynamic_update_slice(x, y, (start,))
+                        for x, y in zip(table, window)))
+    return table, jnp.zeros_like(failed).at[lane].set(fits)
 
 
 def remove(table: EdgeTable, u: jax.Array, v: jax.Array, max_probes: int,

@@ -51,15 +51,17 @@ class RepairStats(NamedTuple):
     region_edges: jax.Array     # int32[]  live intra-region edges this step
     reach_rounds: jax.Array     # int32[]  FW/BW fixpoint iterations
     scc_rounds: jax.Array       # int32[]  the chosen tier's iterations
+    layout: jax.Array           # int32[]  layout.LAYOUT_NONE..LAYOUT_FALLBACK
 
 
 def repair_skipped() -> RepairStats:
     """The stats a gated (structure-preserving) step reports: no tier ran,
-    no region was materialized, no fixpoint iterated."""
+    no region was materialized, no fixpoint iterated, no layout was
+    built."""
     zero = jnp.int32(0)
     return RepairStats(tier=jnp.int32(TIER_SKIP), region_vertices=zero,
                        region_edges=zero, reach_rounds=zero,
-                       scc_rounds=zero)
+                       scc_rounds=zero, layout=zero)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,13 +81,15 @@ class GraphConfig:
     # which implementation backs the *sparse* hot loop: every FW/BW
     # fixpoint round (kernels.frontier_expand segment-min) and every
     # edge-table probe (kernels.hash_probe fused sweep).  'auto' = Pallas
-    # on TPU within the kernels' size ceilings, XLA scatter/probe-loop
-    # otherwise; 'pallas' / 'pallas_interpret' force the kernel; 'xla' is
-    # the differential oracle the fuzz suites A/B against.  Unlike
-    # dense_matmul_impl, CPU 'auto' resolves to 'xla' (not interpret):
-    # these sweeps are always-on, and interpret-executing them would
-    # regress every step by orders of magnitude -- the interpret path is
-    # exercised by the forced-impl test suites instead.
+    # on TPU within the kernels' size ceilings, the sorted live-edge
+    # layout (core.layout) for the repair sweeps on TPU above them, XLA
+    # scatter/probe-loop otherwise; 'pallas' / 'pallas_interpret' /
+    # 'sorted' force one ('sorted' leaves the kernels on 'auto', see
+    # kernel_impl); 'xla' is the differential oracle the fuzz suites A/B
+    # against.  Unlike dense_matmul_impl, CPU 'auto' resolves to 'xla'
+    # (not interpret): these sweeps are always-on, and interpret-executing
+    # them would regress every step by orders of magnitude -- the
+    # interpret path is exercised by the forced-impl test suites instead.
     sparse_impl: str = "auto"
     # compact-sparse repair tier: >0 (and < n_vertices) compacts affected
     # regions of at most this many vertices into bounded sub-arrays so each
@@ -130,7 +134,14 @@ class GraphConfig:
             "region_edge_buckets must be positive")
         assert self.region_vertex_capacity >= 0
         assert self.sparse_impl in ("auto", "pallas", "pallas_interpret",
-                                    "xla"), self.sparse_impl
+                                    "sorted", "xla"), self.sparse_impl
+
+    @property
+    def kernel_impl(self) -> str:
+        """``sparse_impl`` as the kernels take it: ``'sorted'`` names the
+        repair sweeps' edge layout, and leaves every kernel on
+        ``'auto'``."""
+        return "auto" if self.sparse_impl == "sorted" else self.sparse_impl
 
 
 class GraphState(NamedTuple):
@@ -172,7 +183,7 @@ def from_arrays(cfg: GraphConfig, src, dst, n_active_vertices=None) -> GraphStat
     # overflow = keys the table itself reports dropped on probe exhaustion
     # (duplicates in the input are found / deduped, so they do not count).
     table, _, failed = et.insert(state.edges, src, dst, cfg.max_probes,
-                                 impl=cfg.sparse_impl)
+                                 impl=cfg.kernel_impl)
     state = state._replace(
         v_alive=v_alive,
         edges=table,

@@ -42,7 +42,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core import reach
+from repro.core import layout as lay, reach
 
 INT32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -53,16 +53,26 @@ def _degrees(src, dst, emask, nv):
     return indeg, outdeg
 
 
-def trim(src, dst, live, unassigned, vid, ccid, max_iters: int):
+def trim(src, dst, live, unassigned, vid, ccid, max_iters: int,
+         layout=None):
     """Iteratively peel zero-in/out-degree vertices into singleton SCCs.
-    Returns (unassigned, ccid, rounds)."""
+    Returns (unassigned, ccid, rounds).  On a ``layout``
+    (:class:`repro.core.layout.EdgeLayout` of the same edges) a vertex
+    keeps an in- (out-) edge when any predecessor (successor) is
+    unassigned: an unassigned vertex's own bit is set, and only
+    unassigned vertices peel."""
     nv = unassigned.shape[0]
 
     def body(carry):
         unassigned, ccid = carry
-        emask = live & unassigned[src] & unassigned[dst]
-        indeg, outdeg = _degrees(src, dst, emask, nv)
-        peel = unassigned & ((indeg == 0) | (outdeg == 0))
+        if layout is not None:
+            bit = jnp.where(unassigned, reach.ZERO_U32, reach.SENT)
+            keep = lay.pull_both(jnp.stack([bit, bit]), layout) == 0
+            peel = unassigned & ~(keep[0] & keep[1])
+        else:
+            emask = live & unassigned[src] & unassigned[dst]
+            indeg, outdeg = _degrees(src, dst, emask, nv)
+            peel = unassigned & ((indeg == 0) | (outdeg == 0))
         ccid = jnp.where(peel, vid, ccid)
         return (unassigned & ~peel, ccid), jnp.any(peel)
 
@@ -90,10 +100,12 @@ def scc_static(src, dst, live, active, *, max_outer: int, max_inner: int,
 
 def scc_static_rounds(src, dst, live, active, *, max_outer: int,
                       max_inner: int, spec=None, shortcut: bool = False,
-                      impl: str = "xla"):
+                      impl: str = "xla", layout=None):
     """:func:`scc_static` (unjitted) with its iteration count: returns
     ``(ccid, rounds)``, rounds being every trim and propagation iteration,
-    summed over the outer rounds."""
+    summed over the outer rounds.  ``layout`` (a
+    :class:`repro.core.layout.EdgeLayout` of the same edges) replaces the
+    scatter in every sweep."""
     nv = active.shape[0]
     vid = jnp.arange(nv, dtype=jnp.int32)
     ccid = jnp.full((nv,), INT32_MAX, jnp.int32)
@@ -107,7 +119,7 @@ def scc_static_rounds(src, dst, live, active, *, max_outer: int,
         unassigned, ccid, it, rounds = carry
         # (1) trim
         unassigned, ccid, r_trim = trim(src, dst, live, unassigned, vid,
-                                        ccid, max_inner)
+                                        ccid, max_inner, layout=layout)
         # (2) forward-min and backward-min witnesses within unassigned:
         # fwd[v] = min-priority vertex reaching v, bwd[v] = min-priority
         # vertex v reaches.  A vertex sits in a finished SCC exactly when
@@ -117,12 +129,17 @@ def scc_static_rounds(src, dst, live, active, *, max_outer: int,
         # + boolean backward sweep, whose backward phase is pinned at
         # O(diameter) rounds.
         if shortcut:
-            fwd, r_fwd = reach.propagate_min_prio(
-                src, dst, live, unassigned, max_inner, spec=spec,
-                impl=impl)
-            bwd, r_bwd = reach.propagate_min_prio(
-                dst, src, live, unassigned, max_inner, spec=spec,
-                impl=impl)
+            if layout is not None:
+                fwd, bwd, r_prop = lay.prio_pair(layout, unassigned,
+                                                 max_inner, spec=spec)
+            else:
+                fwd, r_fwd = reach.propagate_min_prio(
+                    src, dst, live, unassigned, max_inner, spec=spec,
+                    impl=impl)
+                bwd, r_bwd = reach.propagate_min_prio(
+                    dst, src, live, unassigned, max_inner, spec=spec,
+                    impl=impl)
+                r_prop = r_fwd + r_bwd
             done = unassigned & (fwd == bwd) & (fwd < nv)
             # canonical label = min member id of each witness group
             grp = jnp.where(done, fwd, nv)
@@ -131,16 +148,21 @@ def scc_static_rounds(src, dst, live, active, *, max_outer: int,
             ccid = jnp.where(done, min_id[jnp.minimum(fwd, nv)], ccid)
         else:
             init = jnp.where(unassigned, vid, INT32_MAX)
-            fwd, r_fwd = reach.propagate_min_labels(
-                src, dst, live, init, unassigned, max_inner, spec=spec,
-                impl=impl)
-            bwd, r_bwd = reach.propagate_min_labels(
-                dst, src, live, init, unassigned, max_inner, spec=spec,
-                impl=impl)
+            if layout is not None:
+                fwd, bwd, r_prop = lay.propagate_pair(
+                    layout, init, unassigned, max_inner, spec=spec)
+            else:
+                fwd, r_fwd = reach.propagate_min_labels(
+                    src, dst, live, init, unassigned, max_inner, spec=spec,
+                    impl=impl)
+                bwd, r_bwd = reach.propagate_min_labels(
+                    dst, src, live, init, unassigned, max_inner, spec=spec,
+                    impl=impl)
+                r_prop = r_fwd + r_bwd
             done = unassigned & (fwd == bwd)
             ccid = jnp.where(done, fwd, ccid)
         unassigned = unassigned & ~done
-        return unassigned, ccid, it + 1, rounds + r_trim + r_fwd + r_bwd
+        return unassigned, ccid, it + 1, rounds + r_trim + r_prop
 
     _, ccid, _, rounds = jax.lax.while_loop(
         outer_cond, outer_body,
@@ -224,9 +246,24 @@ def scc_compact_region(src, dst, live, region_mask, v_capacity: int,
     produce canonical min-member-id labels and the compact enumeration is
     order-preserving; ``rounds`` as :func:`scc_static_rounds` counts them.
     """
-    nv = region_mask.shape[0]
     csrc, cdst, celive, ids, valid, _, fits = compact_region(
         src, dst, live, region_mask, v_capacity, e_capacity)
+    ccid, rounds = compact_labels(
+        csrc, cdst, celive, ids, valid, region_mask.shape[0],
+        max_outer=max_outer, max_inner=max_inner, shortcut=shortcut,
+        impl=impl)
+    return ccid, fits, rounds
+
+
+def compact_labels(csrc, cdst, celive, ids, valid, nv: int, *,
+                   max_outer: int, max_inner: int, shortcut: bool = False,
+                   impl: str = "xla"):
+    """The labels of :func:`scc_compact_region` from the outputs of
+    :func:`compact_region`: ``(ccid int32[NV], rounds)``.  A prefix of the
+    compact edge list is the compaction into that many edge slots, since
+    the enumeration is stable, so one compaction serves every smaller
+    bucket."""
+    v_capacity = ids.shape[0]
     # no spec: the whole point is that compact operands are small enough to
     # stay replicated, round after round
     clab, rounds = scc_static_rounds(
@@ -239,7 +276,7 @@ def scc_compact_region(src, dst, live, region_mask, v_capacity: int,
                      ids[jnp.clip(clab, 0, v_capacity - 1)], INT32_MAX)
     ccid = jnp.full((nv,), INT32_MAX, jnp.int32)
     ccid = ccid.at[jnp.where(valid, ids, nv)].set(glab, mode="drop")
-    return ccid, fits, rounds
+    return ccid, rounds
 
 
 # ---------------------------------------------------------------------------

@@ -71,7 +71,7 @@ import numpy as np
 
 from repro import telemetry
 from repro.core import community, dynamic, edge_table as et
-from repro.core import graph_state as gs
+from repro.core import graph_state as gs, layout as lay
 from repro.fault import errors as fault_errors
 
 _MAX_GROW_ROUNDS = 16
@@ -150,7 +150,7 @@ def reachable_on(state: gs.GraphState, cfg: gs.GraphConfig, u, v
     """bool[Q]: u[i] ⇝ v[i] on a pinned snapshot."""
     res = _reachable_batch(state, jnp.asarray(u, jnp.int32),
                            jnp.asarray(v, jnp.int32), cfg.max_inner,
-                           impl=cfg.sparse_impl)
+                           impl=cfg.kernel_impl)
     return np.asarray(res) & _ids_in_range(u, cfg.n_vertices) \
         & _ids_in_range(v, cfg.n_vertices)
 
@@ -256,6 +256,8 @@ class SCCService:
         # lazily, next to the overflow delta; "skipped" counts steps the
         # repair gate proved structure-preserving)
         self.repair_tier_steps = {name: 0 for name in dynamic.TIER_NAMES}
+        # the edge layout each step's sweeps ran on (lay.LAYOUT_NAMES)
+        self.repair_layout_steps = {name: 0 for name in lay.LAYOUT_NAMES}
         self.repair_region_v_max = 0
         self.repair_region_e_max = 0
         self.repair_reach_rounds = 0
@@ -339,6 +341,7 @@ class SCCService:
         snap = {a: getattr(self, a) for a in self._STAT_ATTRS}
         snap["_compiled"] = set(self._compiled)
         snap["repair_tier_steps"] = dict(self.repair_tier_steps)
+        snap["repair_layout_steps"] = dict(self.repair_layout_steps)
         return snap
 
     def _stats_restore(self, snap: dict):
@@ -346,6 +349,7 @@ class SCCService:
             setattr(self, a, snap[a])
         self._compiled = snap["_compiled"]
         self.repair_tier_steps = snap["repair_tier_steps"]
+        self.repair_layout_steps = snap["repair_layout_steps"]
 
     def _apply_chunk(self, kind, u, v) -> np.ndarray:
         """Apply a variable-length op stream chunk; returns ok: bool[N].
@@ -480,7 +484,7 @@ class SCCService:
         kv[:n_keys] = keys[:, 1]
         found, _ = et.lookup(self._state.edges, jnp.asarray(ku),
                              jnp.asarray(kv), self._cfg.max_probes,
-                             impl=self._cfg.sparse_impl)
+                             impl=self._cfg.kernel_impl)
         n_new = int(np.sum(~np.asarray(found)[:n_keys]))
         predicted = live + n_new - n_rem
         if predicted <= self._cfg.edge_capacity:
@@ -603,20 +607,22 @@ class SCCService:
         return ok, None
 
     def _record_repair(self, tier, region_v, region_e, reach_rounds,
-                       scc_rounds):
+                       scc_rounds, layout):
         """Count one resolved step's ``RepairStats`` (host scalars, in
         leaf order) and emit its ``repair.step`` event."""
         name = dynamic.TIER_NAMES[int(tier)]
+        layout = lay.LAYOUT_NAMES[int(layout)]
         region_v, region_e = int(region_v), int(region_e)
         reach_rounds, scc_rounds = int(reach_rounds), int(scc_rounds)
         self.repair_tier_steps[name] += 1
+        self.repair_layout_steps[layout] += 1
         self.repair_region_v_max = max(self.repair_region_v_max, region_v)
         self.repair_region_e_max = max(self.repair_region_e_max, region_e)
         self.repair_reach_rounds += reach_rounds
         self.repair_scc_rounds += scc_rounds
         telemetry.event("repair.step", tier=name, region_v=region_v,
                         region_e=region_e, reach_rounds=reach_rounds,
-                        scc_rounds=scc_rounds)
+                        scc_rounds=scc_rounds, layout=layout)
 
     def _apply_padded(self, ops: dynamic.OpBatch, depth: int = 0
                       ) -> np.ndarray:
@@ -674,7 +680,7 @@ class SCCService:
             return cand
         found, _ = et.lookup(self._state.edges, ops.u, ops.v,
                              self._cfg.max_probes,
-                             impl=self._cfg.sparse_impl)
+                             impl=self._cfg.kernel_impl)
         return cand & ~np.asarray(found)
 
     def grow(self, new_capacity: int | None = None):
@@ -702,7 +708,7 @@ class SCCService:
                     f"edge table would exceed max_edge_capacity "
                     f"({cap} > {self._max_edge_capacity})")
             table = _rehash(self._state.edges, cap, self._cfg.max_probes,
-                            impl=self._cfg.sparse_impl)
+                            impl=self._cfg.kernel_impl)
             live_after, _ = et.fill_stats(table)
             if int(live_after) == int(live_before):
                 self._live_ub = int(live_after)  # sync already paid
@@ -783,9 +789,9 @@ class SCCService:
             "kernel_impl": {
                 "sparse_impl": self._cfg.sparse_impl,
                 "frontier_expand": frontier_ops.resolve_impl(
-                    self._cfg.sparse_impl, self._cfg.n_vertices),
+                    self._cfg.kernel_impl, self._cfg.n_vertices),
                 "hash_probe": hash_probe_ops.resolve_impl(
-                    self._cfg.sparse_impl, self._cfg.edge_capacity),
+                    self._cfg.kernel_impl, self._cfg.edge_capacity),
                 "dense_matmul": blockmm_ops._resolve(
                     self._cfg.dense_matmul_impl),
             },
@@ -812,5 +818,7 @@ class SCCService:
             "repair_region_e_max": self.repair_region_e_max,
             "repair_reach_rounds": self.repair_reach_rounds,
             "repair_scc_rounds": self.repair_scc_rounds,
+            **{f"repair_layout_{k}_steps": n
+               for k, n in self.repair_layout_steps.items()},
             "deduped_resubmits": self.deduped_resubmits,
         }

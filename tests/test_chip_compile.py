@@ -5,8 +5,9 @@ described rather than present.  That refuses what interpret mode accepts:
 unsupported reductions, block shapes off the (8, 128) tiling, kernels that
 overflow VMEM, programs that do not fit the chip's 16 GB of HBM.  So each
 case below compiles a Pallas kernel of the main path at the width it runs
-at, or a whole update step (``update_1m``, and the tenant engine's
-vmapped whole-lane dense step), for one chip of a ``v5e:2x2`` slice.
+at, or a whole update step (``update_1m`` on the kernels and on the
+sorted edge layout, and the tenant engine's vmapped whole-lane dense
+step), for one chip of a ``v5e:2x2`` slice.
 
 Every case forces ``impl='pallas'``: ``jax.default_backend()`` reads CPU
 here, so ``'auto'`` would pick the XLA path and compile no kernel.  The
@@ -137,6 +138,36 @@ def test_update_1m_scan_step_fits_one_chip(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < V5E_HBM_BYTES, total
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_update_1m_step_on_the_sorted_layout_fits_one_chip(one_chip):
+    """The donated single update step at update_1m with its repair sweeps
+    on the sorted edge layout, as ``'auto'`` resolves them on a TPU at
+    2^20 vertices: it sorts the table, fits one chip's HBM, and adds at
+    most 16 MB of program code to the scatter step's, since a TPU keeps a
+    loaded program's code in device memory."""
+    shape = smscc.SHAPES["update_1m"]
+    lanes = _sds(one_chip, (shape["batch"],), jnp.int32)
+    ops = dynamic.OpBatch(kind=lanes, u=lanes, v=lanes)
+    code = {}
+    for impl in ("sorted", "xla"):
+        cfg = smscc.config(n_vertices=shape["n_vertices"],
+                           edge_capacity=shape["edge_capacity"],
+                           sparse_impl=impl)
+        state = jax.tree.map(
+            lambda s: _sds(one_chip, s.shape, s.dtype),
+            jax.eval_shape(lambda: gs.empty(cfg)))
+        compiled = jax.jit(
+            lambda s, o: dynamic._apply_batch_impl(s, o, cfg),
+            donate_argnums=(0,)).lower(state, ops).compile()
+        mem = compiled.memory_analysis()
+        code[impl] = mem.generated_code_size_in_bytes
+        if impl == "sorted":
+            total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                     + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+            assert 0 < total < V5E_HBM_BYTES, total
+            assert " sort(" in compiled.as_text()
+    assert 0 < code["sorted"] - code["xla"] < 16e6, code
 
 
 def test_tenant_dense_lane_step_fits_one_chip(one_chip):

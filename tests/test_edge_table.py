@@ -130,3 +130,33 @@ def test_overflow_reports_failure():
     assert not to_np(failed2).any()
     _, _, failed3 = et.insert(t2, du, dv, 8)
     assert not to_np(failed3).any()
+
+
+def test_reorder_chain_places_a_key_whose_probe_window_is_full():
+    """At about half load a short probe bound leaves keys whose window
+    earlier keys filled; re-laying each one's chain in home order places
+    it, and every key already in the table stays where lookups find it.
+    A table below ``RELAYOUT_MIN_CAP`` slots is left to grow."""
+    cap, probes = et.RELAYOUT_MIN_CAP, 16
+    rng = np.random.default_rng(7)
+    keys = np.unique(rng.integers(0, 1 << 30, 496_000))
+    u = jnp.asarray(keys >> 15, jnp.int32)
+    v = jnp.asarray(keys & 0x7FFF, jnp.int32)
+    t, ins, failed = _insert(et.empty(cap), u, v, max_probes=probes)
+    lanes = np.nonzero(to_np(failed))[0]
+    assert 0 < lanes.size < 3000
+    reorder = jax.jit(et.reorder_chain, static_argnames=("max_probes",))
+    rescued = jnp.zeros_like(failed)
+    for lane in lanes[:48]:  # one failed lane a call, in lane order
+        t, got = reorder(t, u, v, jnp.zeros_like(failed).at[lane].set(True),
+                         max_probes=probes)
+        rescued = rescued | got
+    assert int(jnp.sum(rescued)) > 0
+    found, _ = _lookup(t, u, v, max_probes=probes)
+    np.testing.assert_array_equal(to_np(found), to_np(ins | rescued))
+    live = to_np(t.state) == 1
+    assert int(live.sum()) == int(jnp.sum(ins | rescued))
+    small = et.empty(et.RELAYOUT_MIN_CAP // 2)
+    _, none = et.reorder_chain(small, u[:8], v[:8], jnp.ones((8,), bool),
+                               probes)
+    assert not to_np(none).any()
